@@ -7,6 +7,7 @@ import time
 from pathlib import Path
 from typing import Dict
 
+import jax
 import numpy as np
 
 from repro.core.router import (EagleConfig, EagleRouter, GlobalOnlyRouter,
@@ -83,10 +84,6 @@ def timer(fn, *args, repeat: int = 3, **kw):
     for _ in range(repeat):
         t0 = time.perf_counter()
         out = fn(*args, **kw)
-        try:
-            import jax
-            jax.block_until_ready(out)
-        except Exception:
-            pass
+        jax.block_until_ready(out)
         ts.append((time.perf_counter() - t0) * 1e6)
     return float(np.median(ts)), out

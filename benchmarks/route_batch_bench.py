@@ -293,24 +293,21 @@ def run_ragged(verbose: bool = True, smoke: bool = False,
 # sharded routing gate (ci.sh --assert-sharded)
 # ---------------------------------------------------------------------------
 
-def _reexec_with_devices(n: int):
-    """The forced-host-device XLA flag must be set before jax
-    initializes; jax imported at this module's top, so when the process
-    lacks devices for an N-shard mesh the run re-execs itself with the
-    flag merged into XLA_FLAGS. Returns the child's exit code, or None
-    when this process already has enough devices."""
-    if jax.device_count() >= n:
-        return None
+def _claim_devices(n: int):
+    """Give this process the n devices an n-shard mesh needs, in this
+    process (a child could not reach a chip the parent holds). Under
+    JAX_PLATFORMS=cpu the CPU backend is split into n host devices,
+    which must happen before the backend starts; anywhere else the run
+    uses the devices it has and fails when they are too few."""
     import os
-    import subprocess
-    import sys
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={n}"
-                        ).strip()
-    return subprocess.call(
-        [sys.executable, "-m", "benchmarks.route_batch_bench",
-         *sys.argv[1:]], env=env)
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        jax.config.update("jax_num_cpu_devices", n)
+    have = jax.device_count()
+    if have < n:
+        raise SystemExit(
+            f"--mesh {n} needs {n} devices; this process has {have} "
+            f"({jax.devices()[0].platform}). On a CPU host run it under "
+            f"JAX_PLATFORMS=cpu.")
 
 
 def run_sharded(verbose: bool = True, smoke: bool = False,
@@ -644,16 +641,14 @@ if __name__ == "__main__":
                          "post-warmup compiles")
     ap.add_argument("--mesh", type=int, default=0, metavar="N",
                     help="run the ragged loop over an N-shard DB mesh "
-                         "(re-execs with forced host devices if needed)")
+                         "(under JAX_PLATFORMS=cpu: N forced host devices)")
     ap.add_argument("--assert-sharded", action="store_true",
                     help="with --mesh: fail on any post-warmup compile "
                          "or any divergence from the single-device "
                          "oracle")
     args = ap.parse_args()
     if args.mesh:
-        rc = _reexec_with_devices(args.mesh)
-        if rc is not None:
-            raise SystemExit(rc)
+        _claim_devices(args.mesh)
         run_sharded(smoke=args.smoke, mesh_n=args.mesh,
                     assert_sharded=args.assert_sharded)
     elif args.obs or args.assert_obs:

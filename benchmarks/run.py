@@ -19,6 +19,8 @@ def main():
                     help="single seed (CI-speed)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import common as C
     seeds = (0,) if args.quick else C.SEEDS
 

@@ -26,6 +26,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 DEFAULT_RATING = 1000.0
 
@@ -99,13 +100,15 @@ def _pad_bucket(t: int, floor: int = 64) -> int:
 
 
 def _scan_padded(ratings, a_idx, b_idx, outcome, k):
-    t = a_idx.shape[0]
+    """Pad the records to their bucket on the host: a device-side pad
+    would compile once per distinct record count, on the serving path."""
+    t = np.shape(a_idx)[0]
     tb = _pad_bucket(t)
     pad = tb - t
-    a = jnp.pad(jnp.asarray(a_idx, jnp.int32), (0, pad))
-    b = jnp.pad(jnp.asarray(b_idx, jnp.int32), (0, pad))
-    s = jnp.pad(jnp.asarray(outcome, jnp.float32), (0, pad))
-    v = jnp.arange(tb) < t
+    a = np.pad(np.asarray(a_idx, np.int32), (0, pad))
+    b = np.pad(np.asarray(b_idx, np.int32), (0, pad))
+    s = np.pad(np.asarray(outcome, np.float32), (0, pad))
+    v = np.arange(tb) < t
     return elo_scan(ratings, a, b, s, v, k=k)
 
 

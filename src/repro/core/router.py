@@ -110,9 +110,7 @@ class EagleRouter:
         t0 = time.perf_counter()
         self.db.add(embeddings, model_a, model_b, outcome, query_id)
         self.global_ratings = elo.fit_global(
-            self.n_models, jnp.asarray(model_a, jnp.int32),
-            jnp.asarray(model_b, jnp.int32),
-            jnp.asarray(outcome, jnp.float32),
+            self.n_models, model_a, model_b, outcome,
             k=self.cfg.k_factor, init=self.cfg.init_rating)
         self.global_ratings.block_until_ready()
         self._stale = True
@@ -124,8 +122,7 @@ class EagleRouter:
         t0 = time.perf_counter()
         self.db.add(embeddings, model_a, model_b, outcome, query_id)
         self.global_ratings = elo.update_global(
-            self.global_ratings, jnp.asarray(model_a, jnp.int32),
-            jnp.asarray(model_b, jnp.int32), jnp.asarray(outcome, jnp.float32),
+            self.global_ratings, model_a, model_b, outcome,
             k=self.cfg.k_factor)
         self.global_ratings.block_until_ready()
         self._stale = True

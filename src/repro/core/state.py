@@ -28,7 +28,6 @@ from typing import Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs as OBS
@@ -119,16 +118,19 @@ def init_state(n_models: int, dim: int, capacity: int = 4096,
         size=jnp.int32(0))
 
 
-def state_from_buffer(db, global_ratings) -> RouterState:
-    """Full upload of a host append buffer (VectorDB) to device."""
-    return RouterState(
-        global_ratings=jnp.asarray(global_ratings, jnp.float32),
-        emb=jnp.asarray(db.emb),
-        model_a=jnp.asarray(db.model_a),
-        model_b=jnp.asarray(db.model_b),
-        outcome=jnp.asarray(db.outcome),
-        valid=jnp.asarray(db.valid),
-        size=jnp.int32(db.size))
+def state_from_buffer(db, global_ratings,
+                      shardings: Optional[RouterState] = None
+                      ) -> RouterState:
+    """Full upload of a host append buffer (VectorDB) to device. With
+    `shardings` (state_shardings(mesh)) each panel goes from the host
+    straight to its shards, never whole through one device."""
+    host = RouterState(
+        global_ratings=np.asarray(global_ratings, np.float32),
+        emb=db.emb, model_a=db.model_a, model_b=db.model_b,
+        outcome=db.outcome, valid=db.valid, size=np.int32(db.size))
+    if shardings is None:
+        return jax.tree.map(jnp.asarray, host)
+    return jax.tree.map(jax.device_put, host, shardings)
 
 
 @partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
@@ -185,8 +187,8 @@ def _sharded_scatter(mesh: Mesh):
                 outcome.at[rows].set(o_rows),
                 valid.at[rows].set(v_rows))
 
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,) * 11,
-                           out_specs=(spec,) * 5, check_rep=False),
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 11,
+                               out_specs=(spec,) * 5, check_vma=False),
                  donate_argnums=(0, 1, 2, 3, 4))
     _SHARDED_SCATTER[mesh] = fn
     return fn
@@ -203,7 +205,7 @@ def _commit_sharded(db, global_ratings, prev: Optional[RouterState],
     per_shard = db.drain_dirty_sharded(consumer, shards)
     if (prev is None or prev.emb.shape != db.emb.shape
             or prev.model_a.shape != db.model_a.shape):
-        return shard_state(state_from_buffer(db, global_ratings), mesh)
+        return state_from_buffer(db, global_ratings, state_shardings(mesh))
     rep = NamedSharding(mesh, P())
     g = jax.device_put(jnp.asarray(global_ratings, jnp.float32), rep)
     size = jax.device_put(jnp.int32(db.size), rep)
@@ -504,13 +506,13 @@ def route_batch_choices_sharded(state: RouterState, query_embs, budgets,
         return choices, top_i
 
     shd = P(axis)
-    # check_rep=False: the merged epilogue output is replicated by
+    # check_vma=False: the merged epilogue output is replicated by
     # construction (every shard reduces the same gathered pool), which
     # shard_map's replication checker cannot prove through all_gather
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), P(), shd, shd, shd, shd, shd,
-                             P(), P(), P(), P()),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), P(), shd, shd, shd, shd, shd,
+                                 P(), P(), P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     with jax.named_scope("eagle.retrieve_replay_select_sharded"):
         choices, top_i = fn(state.global_ratings, init, state.emb,
                             state.model_a, state.model_b, state.outcome,

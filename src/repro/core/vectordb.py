@@ -122,10 +122,43 @@ class VectorDB:
             self.n_rec[row] += 1
             for ledger in self._dirty.values():
                 ledger.add(row)
+        self._appended(b)
+
+    def add_rows(self, emb, model_a, model_b, outcome, n_records,
+                 query_id=None):
+        """Bulk append of whole prompt rows, vectorized (loading a
+        history of 10^5-10^6 prompts through add() would be a Python
+        loop per record). emb: (B, D); records (B, R) with row i's
+        first n_records[i] slots live; query_id: (B,) optional ids that
+        later add() calls can append feedback under."""
+        emb = np.atleast_2d(np.asarray(emb, np.float32))
+        n_records = np.asarray(n_records, np.int32).reshape(-1)
+        b, r = emb.shape[0], np.shape(model_a)[1]
+        if self.size + b > self.capacity:
+            self._grow(need_q=self.size + b)
+        if r > self.rcap:
+            self._grow(need_r=r)
+        rows = slice(self.size, self.size + b)
+        live = np.arange(r)[None, :] < n_records[:, None]
+        self.emb[rows] = _l2norm_np(emb)
+        self.model_a[rows, :r] = np.where(live, model_a, 0)
+        self.model_b[rows, :r] = np.where(live, model_b, 0)
+        self.outcome[rows, :r] = np.where(live, outcome, 0.0)
+        self.valid[rows, :r] = live
+        self.n_rec[rows] = n_records
+        if query_id is not None:
+            self._row_of.update(zip(np.asarray(query_id).tolist(),
+                                    range(rows.start, rows.stop)))
+        for ledger in self._dirty.values():
+            ledger.update(range(rows.start, rows.stop))
+        self.size += b
+        self._appended(int(n_records.sum()))
+
+    def _appended(self, n_records: int):
         self._device = None  # invalidate the device snapshot
         o = OBS.get_obs(None)
         o.registry.counter("vectordb_records_total",
-                           "feedback records appended").inc(b)
+                           "feedback records appended").inc(n_records)
         o.registry.gauge("vectordb_size", "live prompt rows").set(self.size)
         o.registry.gauge("vectordb_capacity",
                          "allocated prompt rows").set(self.capacity)

@@ -154,6 +154,24 @@ def pairwise_feedback(corpus: Corpus, query_idx: np.ndarray, *, seed: int = 0,
     }
 
 
+def bulk_history(rng: np.random.Generator, n_rows: int, dim: int,
+                 n_models: int, records: int = 8):
+    """A stored feedback history at deployment scale, made in bulk:
+    n_rows prompts with random unit-direction embeddings, each holding
+    1..records-1 pairwise records (one slot stays free, so later
+    feedback on a stored prompt appends without regrowing the DB).
+    Returns the VectorDB.add_rows arguments (emb, model_a, model_b,
+    outcome, n_records)."""
+    emb = rng.standard_normal((n_rows, dim), dtype=np.float32)
+    a = rng.integers(0, n_models, (n_rows, records), dtype=np.int32)
+    b = (a + 1 + rng.integers(0, n_models - 1, (n_rows, records),
+                              dtype=np.int32)) % n_models
+    outcome = rng.choice(np.asarray([0.0, 0.5, 1.0], np.float32),
+                         (n_rows, records))
+    n_records = rng.integers(1, records, n_rows, dtype=np.int32)
+    return emb, a, b, outcome, n_records
+
+
 def winrate_targets(fb: Dict[str, np.ndarray], n_models: int):
     """Convert pairwise feedback into per-query per-model win-rate targets —
     the ONLY supervision available to quality regressors in a live system

@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 exposes TPUCompilerParams; newer releases renamed it
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -90,7 +86,7 @@ def decode_attention_pallas(q, k, v, kv_len, *, block_k: int = 256,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), qt, kt, vt)

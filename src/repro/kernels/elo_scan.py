@@ -3,12 +3,14 @@
 Eagle-Local replays N neighbor feedback records per query. The replay is
 sequential in T (a true scan) but embarrassingly parallel across queries.
 GPU thinking assigns one thread per query; on TPU we keep a
-(block_q, n_models) rating tile resident in VMEM and apply each of the T
+(n_models, block_q) rating tile resident in VMEM and apply each of the T
 updates as a one-hot masked add over the whole tile — pure VPU work with
 no gather/scatter (DESIGN.md §3).
 
-Layout: ratings (Q, M) fp32, records (Q, T) int32/fp32. Grid over Q
-blocks; T is walked with a fori_loop inside the kernel.
+Layout: the callers' ratings (Q, M) and records (Q, T) are transposed
+by the wrappers so queries run along lanes: ratings (M, Qp) fp32,
+records (T, Qp) int32/fp32. Grid over Q blocks; T is walked with a
+fori_loop that reads record row i of each (T, block_q) tile.
 """
 from __future__ import annotations
 
@@ -19,81 +21,75 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _elo_kernel(r_ref, a_ref, b_ref, s_ref, v_ref, out_ref, *, k: float):
-    r0 = r_ref[...].astype(jnp.float32)           # (BQ, M)
-    a_all = a_ref[...]
-    b_all = b_ref[...]
-    s_all = s_ref[...].astype(jnp.float32)
-    v_all = v_ref[...].astype(jnp.float32)
-    m = r0.shape[1]
-    t = a_all.shape[1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+def _replay(r0, a_ref, b_ref, s_ref, v_ref, k: float):
+    """Replay the (T, BQ) record tiles into the (M, BQ) rating tile.
+    Queries sit on lanes and models on sublanes, so record i of every
+    query is one dynamic sublane row of each record ref — a ref load
+    the TPU lowering supports, where a dynamic lane slice of a loaded
+    value is not."""
+    m = r0.shape[0]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
 
     def step(i, r):
-        a = jax.lax.dynamic_slice_in_dim(a_all, i, 1, axis=1)  # (BQ,1)
-        b = jax.lax.dynamic_slice_in_dim(b_all, i, 1, axis=1)
-        s = jax.lax.dynamic_slice_in_dim(s_all, i, 1, axis=1)[:, 0]
-        v = jax.lax.dynamic_slice_in_dim(v_all, i, 1, axis=1)[:, 0]
-        one_a = (iota == a).astype(jnp.float32)                # (BQ,M)
+        a = a_ref[pl.ds(i, 1), :]                              # (1, BQ)
+        b = b_ref[pl.ds(i, 1), :]
+        s = s_ref[pl.ds(i, 1), :]
+        v = v_ref[pl.ds(i, 1), :]
+        one_a = (iota == a).astype(jnp.float32)                # (M, BQ)
         one_b = (iota == b).astype(jnp.float32)
-        r_a = jnp.sum(r * one_a, axis=-1)
-        r_b = jnp.sum(r * one_b, axis=-1)
+        r_a = jnp.sum(r * one_a, axis=0, keepdims=True)        # (1, BQ)
+        r_b = jnp.sum(r * one_b, axis=0, keepdims=True)
         e_a = 1.0 / (1.0 + jnp.exp2(jnp.log2(10.0) * (r_b - r_a) / 400.0))
         delta = k * (s - e_a) * v
-        return r + delta[:, None] * (one_a - one_b)
+        return r + delta * (one_a - one_b)
 
-    out_ref[...] = jax.lax.fori_loop(0, t, step, r0)
+    return jax.lax.fori_loop(0, a_ref.shape[0], step, r0)
+
+
+def _elo_kernel(r_ref, a_ref, b_ref, s_ref, v_ref, out_ref, *, k: float):
+    out_ref[...] = _replay(r_ref[...], a_ref, b_ref, s_ref, v_ref, k)
 
 
 def _first_index_where(mask, iota, m):
-    """Index of the first True along the last axis (== jnp.argmax
-    tie-breaking) as a VPU-friendly masked min — no argmax/argmin
+    """Index of the first True along the model (sublane) axis (==
+    jnp.argmax tie-breaking) as a masked min — no argmax/argmin
     primitives inside the kernel body."""
-    return jnp.min(jnp.where(mask, iota, m), axis=-1)
+    return jnp.min(jnp.where(mask, iota, m), axis=0, keepdims=True)
 
 
 def _elo_select_kernel(r_ref, a_ref, b_ref, s_ref, v_ref, g_ref, c_ref,
                        bud_ref, out_ref, ch_ref, *, k: float, p: float):
-    r0 = r_ref[...].astype(jnp.float32)           # (BQ, M)
-    a_all = a_ref[...]
-    b_all = b_ref[...]
-    s_all = s_ref[...].astype(jnp.float32)
-    v_all = v_ref[...].astype(jnp.float32)
-    bq, m = r0.shape
-    t = a_all.shape[1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
-
-    def step(i, r):
-        a = jax.lax.dynamic_slice_in_dim(a_all, i, 1, axis=1)  # (BQ,1)
-        b = jax.lax.dynamic_slice_in_dim(b_all, i, 1, axis=1)
-        s = jax.lax.dynamic_slice_in_dim(s_all, i, 1, axis=1)[:, 0]
-        v = jax.lax.dynamic_slice_in_dim(v_all, i, 1, axis=1)[:, 0]
-        one_a = (iota == a).astype(jnp.float32)                # (BQ,M)
-        one_b = (iota == b).astype(jnp.float32)
-        r_a = jnp.sum(r * one_a, axis=-1)
-        r_b = jnp.sum(r * one_b, axis=-1)
-        e_a = 1.0 / (1.0 + jnp.exp2(jnp.log2(10.0) * (r_b - r_a) / 400.0))
-        delta = k * (s - e_a) * v
-        return r + delta[:, None] * (one_a - one_b)
-
-    r = jax.lax.fori_loop(0, t, step, r0)
+    r = _replay(r_ref[...], a_ref, b_ref, s_ref, v_ref, k)    # (M, BQ)
     out_ref[...] = r
+    m = r.shape[0]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
 
     # budget-selection epilogue, straight out of VMEM: combine with the
     # global prior, mask by affordability, first-max argmax (matching
     # jnp.argmax tie-breaking), cheapest-model fallback.
-    g = g_ref[...].astype(jnp.float32)            # (1, M)
-    c = c_ref[...].astype(jnp.float32)            # (1, M)
-    bud = bud_ref[...].astype(jnp.float32)        # (BQ, 1)
-    combined = p * g + (1.0 - p) * r              # (BQ, M)
-    feasible = c <= bud                           # (BQ, M)
+    g = g_ref[...]                                # (M, 1)
+    c = c_ref[...]                                # (M, 1)
+    bud = bud_ref[...]                            # (1, BQ)
+    combined = p * g + (1.0 - p) * r              # (M, BQ)
+    feasible = c <= bud                           # (M, BQ)
     masked = jnp.where(feasible, combined, -jnp.inf)
-    mx = jnp.max(masked, axis=-1, keepdims=True)
-    choice = _first_index_where(masked == mx, iota, m)      # (BQ,)
-    cmin = jnp.min(c, axis=-1, keepdims=True)
-    fallback = _first_index_where(c == cmin, iota, m)       # (1,)
-    any_ok = jnp.any(feasible, axis=-1)
-    ch_ref[...] = jnp.where(any_ok, choice, fallback)[:, None]
+    mx = jnp.max(masked, axis=0, keepdims=True)
+    choice = _first_index_where(masked == mx, iota, m)       # (1, BQ)
+    cmin = jnp.min(c, axis=0, keepdims=True)
+    fallback = _first_index_where(c == cmin, iota, m)        # (1, 1)
+    any_ok = jnp.max(feasible.astype(jnp.int32), axis=0, keepdims=True) > 0
+    ch_ref[...] = jnp.where(any_ok, choice, fallback)
+
+
+def _lanes_major(block_q, ratings, a_idx, b_idx, outcome, valid):
+    """Pad the query axis to a block multiple and put it on lanes:
+    ratings (Q, M) -> (M, Qp) f32, records (Q, T) -> (T, Qp)."""
+    q = ratings.shape[0]
+    pq = (-q) % block_q
+    pad_t = lambda x: jnp.pad(x, ((0, pq), (0, 0))).T
+    return (pad_t(ratings.astype(jnp.float32)), pad_t(a_idx),
+            pad_t(b_idx), pad_t(outcome.astype(jnp.float32)),
+            pad_t(valid.astype(jnp.float32)))
 
 
 def elo_scan_select_pallas(ratings, a_idx, b_idx, outcome, valid,
@@ -101,7 +97,7 @@ def elo_scan_select_pallas(ratings, a_idx, b_idx, outcome, valid,
                            p: float = 0.5, k: float = 32.0,
                            block_q: int = 128, interpret: bool = False):
     """Batched ELO replay with the budget-selection epilogue fused into
-    the same kernel body: after the T-step replay the (block_q, M)
+    the same kernel body: after the T-step replay the (M, block_q)
     rating tile is combined with the global prior
     (Score = p*Global + (1-p)*Local), budget-masked, and argmax-reduced
     while still resident in VMEM — choices never round-trip a second op
@@ -112,35 +108,30 @@ def elo_scan_select_pallas(ratings, a_idx, b_idx, outcome, valid,
     choices (Q,) int32)."""
     q, m = ratings.shape
     t = a_idx.shape[1]
-    pq = (-q) % block_q
-    pad2 = lambda x: jnp.pad(x, ((0, pq), (0, 0))) if pq else x
-    bud_col = budgets.astype(jnp.float32)[:, None]
-    args = (pad2(ratings.astype(jnp.float32)), pad2(a_idx), pad2(b_idx),
-            pad2(outcome.astype(jnp.float32)),
-            pad2(valid.astype(jnp.float32)),
-            global_ratings.astype(jnp.float32)[None, :],
-            costs.astype(jnp.float32)[None, :], pad2(bud_col))
-    grid = ((q + pq) // block_q,)
+    args = _lanes_major(block_q, ratings, a_idx, b_idx, outcome, valid)
+    qp = args[0].shape[1]
+    bud = jnp.pad(budgets.astype(jnp.float32), (0, qp - q))[None, :]
+    col = lambda x: x.astype(jnp.float32)[:, None]
     out, choices = pl.pallas_call(
         partial(_elo_select_kernel, k=k, p=p),
-        grid=grid,
+        grid=(qp // block_q,),
         in_specs=[
-            pl.BlockSpec((block_q, m), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, t), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, t), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, t), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
+            pl.BlockSpec((m, block_q), lambda i: (0, i)),
+            pl.BlockSpec((t, block_q), lambda i: (0, i)),
+            pl.BlockSpec((t, block_q), lambda i: (0, i)),
+            pl.BlockSpec((t, block_q), lambda i: (0, i)),
+            pl.BlockSpec((t, block_q), lambda i: (0, i)),
+            pl.BlockSpec((m, 1), lambda i: (0, 0)),
+            pl.BlockSpec((m, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, block_q), lambda i: (0, i)),
         ],
-        out_specs=[pl.BlockSpec((block_q, m), lambda i: (i, 0)),
-                   pl.BlockSpec((block_q, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((q + pq, m), jnp.float32),
-                   jax.ShapeDtypeStruct((q + pq, 1), jnp.int32)],
+        out_specs=[pl.BlockSpec((m, block_q), lambda i: (0, i)),
+                   pl.BlockSpec((1, block_q), lambda i: (0, i))],
+        out_shape=[jax.ShapeDtypeStruct((m, qp), jnp.float32),
+                   jax.ShapeDtypeStruct((1, qp), jnp.int32)],
         interpret=interpret,
-    )(*args)
-    return out[:q], choices[:q, 0]
+    )(*args, col(global_ratings), col(costs), bud)
+    return out[:, :q].T, choices[0, :q]
 
 
 def elo_scan_pallas(ratings, a_idx, b_idx, outcome, valid, *, k: float = 32.0,
@@ -148,24 +139,15 @@ def elo_scan_pallas(ratings, a_idx, b_idx, outcome, valid, *, k: float = 32.0,
     """ratings: (Q, M) initial; records (Q, T). Returns (Q, M) replayed."""
     q, m = ratings.shape
     t = a_idx.shape[1]
-    pq = (-q) % block_q
-    pad2 = lambda x: jnp.pad(x, ((0, pq), (0, 0))) if pq else x
-    args = (pad2(ratings.astype(jnp.float32)), pad2(a_idx), pad2(b_idx),
-            pad2(outcome.astype(jnp.float32)),
-            pad2(valid.astype(jnp.float32)))
-    grid = ((q + pq) // block_q,)
+    args = _lanes_major(block_q, ratings, a_idx, b_idx, outcome, valid)
+    qp = args[0].shape[1]
     out = pl.pallas_call(
         partial(_elo_kernel, k=k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_q, m), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, t), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, t), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, t), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, t), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_q, m), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((q + pq, m), jnp.float32),
+        grid=(qp // block_q,),
+        in_specs=[pl.BlockSpec((m, block_q), lambda i: (0, i))]
+        + [pl.BlockSpec((t, block_q), lambda i: (0, i))] * 4,
+        out_specs=pl.BlockSpec((m, block_q), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((m, qp), jnp.float32),
         interpret=interpret,
     )(*args)
-    return out[:q]
+    return out[:, :q].T

@@ -9,10 +9,12 @@ import jax.numpy as jnp
 
 def similarity_ref(q, db):
     """Cosine-similarity score panel. q: (Q,D), db: (N,D) — both rows are
-    L2-normalized by the kernel, so the oracle normalizes too."""
+    L2-normalized by the kernel, so the oracle normalizes too. The dot
+    is pinned at Precision.HIGHEST like the kernel's (DESIGN.md §13):
+    XLA's default f32 dot on the TPU is a single bf16 pass."""
     qn = q / (jnp.linalg.norm(q, axis=-1, keepdims=True) + 1e-9)
     dn = db / (jnp.linalg.norm(db, axis=-1, keepdims=True) + 1e-9)
-    return qn @ dn.T
+    return jnp.matmul(qn, dn.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def elo_scan_ref(ratings, a_idx, b_idx, outcome, valid, k=32.0):

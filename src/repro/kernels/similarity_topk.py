@@ -26,6 +26,7 @@ def _sim_kernel(q_ref, db_ref, out_ref):
     dn = db * jax.lax.rsqrt(jnp.sum(db * db, axis=-1, keepdims=True) + 1e-18)
     out_ref[...] = jax.lax.dot_general(
         qn, dn, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 

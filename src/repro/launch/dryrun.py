@@ -196,11 +196,6 @@ def build_step(cfg, mesh, spec, *, fsdp=False, bf16_params=False,
     bf16_params: serve/train with bf16 parameters (inference-standard).
     opt_state_bf16: AdamW moments in bf16 (halves optimizer memory)."""
     import dataclasses as _dc
-    # bf16-in/f32-accum matmuls: compile-only TPU semantics (XLA:CPU cannot
-    # execute these dots; the dry-run never executes). Set here, not at
-    # module import, so importing this module for its HLO parser does not
-    # change model numerics elsewhere (e.g. under pytest).
-    os.environ["REPRO_TPU_SEMANTICS"] = "1"
     if bf16_params:
         cfg = _dc.replace(cfg, param_dtype="bfloat16")
     if window_cache:

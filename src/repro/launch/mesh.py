@@ -10,6 +10,15 @@ initializes its backend.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    """jax.make_mesh with Auto axes: the sharding rules place
+    with_sharding_constraint on these meshes, which Explicit axes (the
+    make_mesh default of current jax) refuse."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,14 +35,14 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, found {len(devs)} — "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512")
         devs = devs[:need]
-    return jax.make_mesh(shape, axes, devices=devs)
+    return _mesh(shape, axes, devs)
 
 
 def make_test_mesh(model: int = 1, data: int = 1):
     """Tiny mesh over however many (CPU) devices exist — for tests."""
     n = len(jax.devices())
     assert model * data <= n, f"need {model * data} devices, have {n}"
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def make_db_mesh(n_shards: int = 1):
@@ -49,4 +58,4 @@ def make_db_mesh(n_shards: int = 1):
     assert len(devs) >= n_shards, (
         f"DB mesh needs {n_shards} devices, found {len(devs)} — run under "
         f"XLA_FLAGS=--xla_force_host_platform_device_count={n_shards}")
-    return jax.make_mesh((n_shards,), (DB_AXIS,), devices=devs[:n_shards])
+    return _mesh((n_shards,), (DB_AXIS,), devs[:n_shards])

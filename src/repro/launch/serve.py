@@ -18,6 +18,7 @@ from repro.data.routerbench import make_corpus, pairwise_feedback
 from repro.obs.alerts import LogFileSink
 from repro.obs.exporter import ObsExporter
 from repro.obs.quality import RouterQualityMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_db_mesh
 from repro.obs.slo import SLOEngine, default_serving_rules
 from repro.serving.admission import AdmissionQueue
@@ -137,6 +138,7 @@ def main():
                          "in the background before the DB grows")
     args = ap.parse_args()
 
+    enable_compile_cache()
     obs = OBS.Observability(enabled=True) if args.serve_obs is not None \
         else None
     engine, corpus = build_engine(args.fleet, seed=args.seed, obs=obs,

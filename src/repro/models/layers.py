@@ -16,24 +16,19 @@ import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
 
-import os
-
-
-def _mm_kwargs():
-    """TPU-semantics matmuls keep bf16 inputs with f32 accumulation
-    (preferred_element_type). XLA:CPU cannot EXECUTE bf16xbf16->f32 dots
-    (it compiles them fine — the dry-run sets REPRO_TPU_SEMANTICS=1), so
-    CPU execution paths upcast instead."""
-    if os.environ.get("REPRO_TPU_SEMANTICS"):
-        return {"preferred_element_type": jnp.float32}
-    return None
-
 
 def _dotf32(spec, a, b):
-    kw = _mm_kwargs()
-    if kw is not None:
-        return jnp.einsum(spec, a, b, **kw)
-    return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32))
+    """f32-accumulated matmul. On the TPU the operands stay in their
+    dtype (bf16 on the MXU); XLA:CPU cannot execute every bf16 x bf16
+    -> f32 dot (the MLA contractions fail in DotThunk), so other
+    platforms upcast the operands first. The branch is chosen when the
+    program is lowered for its platform."""
+    return jax.lax.platform_dependent(
+        a, b,
+        tpu=lambda a, b: jnp.einsum(spec, a, b,
+                                    preferred_element_type=jnp.float32),
+        default=lambda a, b: jnp.einsum(spec, a.astype(jnp.float32),
+                                        b.astype(jnp.float32)))
 
 
 def _cast(p, dtype):
