@@ -1,0 +1,19 @@
+"""The routing step's share of the chip's peak: the required FLOPs of
+every route dispatch the trace holds (retrieval and replay,
+bench.lib.work.route_step, live rows only) over the traced stretch's
+seconds times the peak FLOP/s. Dispatches are counted as runs of the
+similarity kernel."""
+from bench.lib import readers as R
+from bench.lib import work
+
+
+def read(ctx):
+    c, tr = ctx["counters"], ctx["trace"]
+    _, calls = tr.op_seconds(R.similarity_kernel(ctx))
+    if not calls or not tr.window_s:
+        return None
+    s = R.router_shapes(ctx)
+    flops, _ = work.route_step(c["window_rows"], s["c"], s["d"], s["n"],
+                               s["r"], s["m"])
+    return R.percent(flops * calls / (tr.window_s
+                                      * ctx["peaks"]["flops_per_s"]))
