@@ -373,20 +373,30 @@ class RouteDispatcher:
         return [(lo, min(lo + self.max_bucket, nq))
                 for lo in range(0, nq, self.max_bucket)]
 
-    def _route_one(self, state: RouterState, q: np.ndarray,
-                   b: np.ndarray) -> np.ndarray:
+    def _launch(self, state: RouterState, q: np.ndarray, b: np.ndarray):
+        """Pad to the bucket, place, and run the cached executable, as
+        the spans `dispatch.put` and `dispatch.launch`. Returns the
+        device result and the real row count."""
         nq = q.shape[0]
         qb = self.bucket(nq)
         self._record_dispatch(nq, qb)
-        with self.obs.span("dispatch.route"):
+        obs = self.obs
+        with obs.span("dispatch.put"):
             if qb != nq:
                 q = np.pad(q, ((0, qb - nq), (0, 0)))
                 b = np.pad(b, (0, qb - nq))
             if self._rep is not None:
                 q = jax.device_put(q, self._rep)
                 b = jax.device_put(b, self._rep)
-            res = self._compiled(state, qb)(state, q, b, self.costs)
-            return np.asarray(res.choices)[:nq]
+        with obs.span("dispatch.launch"):
+            return self._compiled(state, qb)(state, q, b, self.costs), nq
+
+    def _route_one(self, state: RouterState, q: np.ndarray,
+                   b: np.ndarray) -> np.ndarray:
+        with self.obs.span("dispatch.route"):
+            res, nq = self._launch(state, q, b)
+            with self.obs.span("dispatch.readout"):
+                return np.asarray(res.choices)[:nq]
 
     def route(self, state: RouterState, query_embs, budgets) -> np.ndarray:
         """Bucket-pad, dispatch the cached executable, slice. Returns
@@ -404,18 +414,11 @@ class RouteDispatcher:
 
     def _route_result_one(self, state: RouterState, q: np.ndarray,
                           b: np.ndarray):
-        nq = q.shape[0]
-        qb = self.bucket(nq)
-        self._record_dispatch(nq, qb)
         with self.obs.span("dispatch.route_result"):
-            qp = np.pad(q, ((0, qb - nq), (0, 0))) if qb != nq else q
-            bp = np.pad(b, (0, qb - nq)) if qb != nq else b
-            if self._rep is not None:
-                qp = jax.device_put(qp, self._rep)
-                bp = jax.device_put(bp, self._rep)
-            res = self._compiled(state, qb)(state, qp, bp, self.costs)
-            return (np.asarray(res.choices)[:nq],
-                    np.asarray(res.topk_idx)[:nq])
+            res, nq = self._launch(state, q, b)
+            with self.obs.span("dispatch.readout"):
+                return (np.asarray(res.choices)[:nq],
+                        np.asarray(res.topk_idx)[:nq])
 
     def route_result(self, state: RouterState, query_embs, budgets):
         """Bucketed dispatch returning (choices (Q,), topk_idx (Q, n))

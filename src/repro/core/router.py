@@ -118,13 +118,18 @@ class EagleRouter:
 
     def update(self, embeddings, model_a, model_b, outcome,
                query_id=None) -> float:
-        """Incremental online update: O(new records), no retraining."""
+        """Incremental online update: O(new records), no retraining.
+        Spans: `router.feedback.add` (the DB append) and
+        `router.feedback.fold` (the global fold, to its completion)."""
+        o = OBS.get_obs(self.obs)
         t0 = time.perf_counter()
-        self.db.add(embeddings, model_a, model_b, outcome, query_id)
-        self.global_ratings = elo.update_global(
-            self.global_ratings, model_a, model_b, outcome,
-            k=self.cfg.k_factor)
-        self.global_ratings.block_until_ready()
+        with o.span("router.feedback.add"):
+            self.db.add(embeddings, model_a, model_b, outcome, query_id)
+        with o.span("router.feedback.fold"):
+            self.global_ratings = elo.update_global(
+                self.global_ratings, model_a, model_b, outcome,
+                k=self.cfg.k_factor)
+            self.global_ratings.block_until_ready()
         self._stale = True
         return time.perf_counter() - t0
 

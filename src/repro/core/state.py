@@ -137,11 +137,12 @@ def state_from_buffer(db, global_ratings,
 def _scatter_rows(emb, model_a, model_b, outcome, valid, rows,
                   emb_rows, a_rows, b_rows, o_rows, v_rows):
     """Write the dirty rows into the donated previous-state buffers."""
-    return (emb.at[rows].set(emb_rows),
-            model_a.at[rows].set(a_rows),
-            model_b.at[rows].set(b_rows),
-            outcome.at[rows].set(o_rows),
-            valid.at[rows].set(v_rows))
+    with jax.named_scope("eagle.commit_scatter"):
+        return (emb.at[rows].set(emb_rows),
+                model_a.at[rows].set(a_rows),
+                model_b.at[rows].set(b_rows),
+                outcome.at[rows].set(o_rows),
+                valid.at[rows].set(v_rows))
 
 
 # ---------------------------------------------------------------------------
@@ -181,11 +182,12 @@ def _sharded_scatter(mesh: Mesh):
 
     def body(emb, model_a, model_b, outcome, valid, rows,
              emb_rows, a_rows, b_rows, o_rows, v_rows):
-        return (emb.at[rows].set(emb_rows),
-                model_a.at[rows].set(a_rows),
-                model_b.at[rows].set(b_rows),
-                outcome.at[rows].set(o_rows),
-                valid.at[rows].set(v_rows))
+        with jax.named_scope("eagle.commit_scatter"):
+            return (emb.at[rows].set(emb_rows),
+                    model_a.at[rows].set(a_rows),
+                    model_b.at[rows].set(b_rows),
+                    outcome.at[rows].set(o_rows),
+                    valid.at[rows].set(v_rows))
 
     fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 11,
                                out_specs=(spec,) * 5, check_vma=False),
@@ -195,7 +197,7 @@ def _sharded_scatter(mesh: Mesh):
 
 
 def _commit_sharded(db, global_ratings, prev: Optional[RouterState],
-                    consumer: str, mesh: Mesh) -> RouterState:
+                    consumer: str, mesh: Mesh, ob) -> RouterState:
     """Sharded commit(): drain the ledger grouped by OWNING shard and
     scatter each group only to its shard (donated buffers). Falls back
     to a full sharded upload on a shape change, like the unsharded
@@ -212,26 +214,31 @@ def _commit_sharded(db, global_ratings, prev: Optional[RouterState],
     if not any(r.size for r in per_shard):
         return dataclasses.replace(prev, global_ratings=g, size=size)
     c_local = db.capacity // shards
-    bucket = elo._pad_bucket(max(r.size for r in per_shard))
-    rows = np.empty((shards, bucket), np.int32)   # GLOBAL row ids
-    for s, r in enumerate(per_shard):
-        pad = r[0] if r.size else s * c_local   # a row shard s owns
-        rows[s, :r.size] = r
-        rows[s, r.size:] = pad
-    flat = rows.reshape(-1)
-    shr = NamedSharding(mesh, P(SHARD.DB_AXIS))
-    put = partial(jax.device_put, device=shr)
-    emb, a, b, o, v = _sharded_scatter(mesh)(
-        prev.emb, prev.model_a, prev.model_b, prev.outcome, prev.valid,
-        put(flat % c_local), put(db.emb[flat]), put(db.model_a[flat]),
-        put(db.model_b[flat]), put(db.outcome[flat]), put(db.valid[flat]))
+    with ob.span("state.commit.gather"):
+        bucket = elo._pad_bucket(max(r.size for r in per_shard))
+        rows = np.empty((shards, bucket), np.int32)   # GLOBAL row ids
+        for s, r in enumerate(per_shard):
+            pad = r[0] if r.size else s * c_local   # a row shard s owns
+            rows[s, :r.size] = r
+            rows[s, r.size:] = pad
+        flat = rows.reshape(-1)
+        host = (flat % c_local, db.emb[flat], db.model_a[flat],
+                db.model_b[flat], db.outcome[flat], db.valid[flat])
+    with ob.span("state.commit.upload"):
+        shr = NamedSharding(mesh, P(SHARD.DB_AXIS))
+        dev = [jax.device_put(x, shr) for x in host]
+    with ob.span("state.commit.scatter"):
+        emb, a, b, o, v = _sharded_scatter(mesh)(
+            prev.emb, prev.model_a, prev.model_b, prev.outcome,
+            prev.valid, *dev)
     return RouterState(global_ratings=g, emb=emb, model_a=a, model_b=b,
                        outcome=o, valid=v, size=size)
 
 
 def commit(db, global_ratings, prev: Optional[RouterState] = None,
            consumer: str = "default",
-           mesh: Optional[Mesh] = None) -> RouterState:
+           mesh: Optional[Mesh] = None,
+           obs: Optional["OBS.Observability"] = None) -> RouterState:
     """Sync the host append buffer into a device RouterState.
 
     With a previous state of matching shape, only the rows touched since
@@ -246,9 +253,15 @@ def commit(db, global_ratings, prev: Optional[RouterState] = None,
     ledger, so rows landing between two replicas' commits reach both.
 
     With a DB `mesh`, the returned state is capacity-sharded and every
-    dirty row is scattered only to its owning shard (DESIGN.md §12)."""
+    dirty row is scattered only to its owning shard (DESIGN.md §12).
+
+    An incremental commit runs as three spans on `obs`:
+    `state.commit.gather` (the dirty rows read from the host buffer),
+    `state.commit.upload` (their transfers) and `state.commit.scatter`
+    (the scatter's enqueue)."""
+    ob = OBS.get_obs(obs)
     if mesh is not None:
-        return _commit_sharded(db, global_ratings, prev, consumer, mesh)
+        return _commit_sharded(db, global_ratings, prev, consumer, mesh, ob)
     rows = db.drain_dirty(consumer)
     if (prev is None or prev.emb.shape != db.emb.shape
             or prev.model_a.shape != db.model_a.shape):
@@ -263,16 +276,20 @@ def commit(db, global_ratings, prev: Optional[RouterState] = None,
     if rows.size == 0:
         return dataclasses.replace(prev, global_ratings=g,
                                    size=jnp.int32(db.size))
-    bucket = elo._pad_bucket(rows.size)
-    # pad by repeating the first dirty row: duplicate scatter writes of
-    # identical content are idempotent
-    rows = np.concatenate([rows, np.full(bucket - rows.size, rows[0],
-                                         rows.dtype)])
-    emb, a, b, o, v = _scatter_rows(
-        prev.emb, prev.model_a, prev.model_b, prev.outcome, prev.valid,
-        jnp.asarray(rows), jnp.asarray(db.emb[rows]),
-        jnp.asarray(db.model_a[rows]), jnp.asarray(db.model_b[rows]),
-        jnp.asarray(db.outcome[rows]), jnp.asarray(db.valid[rows]))
+    with ob.span("state.commit.gather"):
+        bucket = elo._pad_bucket(rows.size)
+        # pad by repeating the first dirty row: duplicate scatter writes
+        # of identical content are idempotent
+        rows = np.concatenate([rows, np.full(bucket - rows.size, rows[0],
+                                             rows.dtype)])
+        host = (rows, db.emb[rows], db.model_a[rows], db.model_b[rows],
+                db.outcome[rows], db.valid[rows])
+    with ob.span("state.commit.upload"):
+        dev = [jnp.asarray(x) for x in host]
+    with ob.span("state.commit.scatter"):
+        emb, a, b, o, v = _scatter_rows(
+            prev.emb, prev.model_a, prev.model_b, prev.outcome,
+            prev.valid, *dev)
     return RouterState(global_ratings=g, emb=emb, model_a=a, model_b=b,
                        outcome=o, valid=v, size=jnp.int32(db.size))
 
@@ -307,9 +324,6 @@ class DoubleBuffer:
         self._g_backlog = r.gauge(
             "dbuf_dirty_backlog",
             "dirty rows pending in the back replica's ledger at commit")
-        self._h_commit_us = r.histogram(
-            "dbuf_commit_us",
-            "host-side commit enqueue latency (scatter is async)")
 
     @property
     def front(self) -> RouterState:
@@ -321,15 +335,12 @@ class DoubleBuffer:
         """Absorb pending feedback into the back replica, swap, return
         the new front. Enqueued asynchronously: routing already in
         flight on the old front is never disturbed."""
-        import time
         st, tag = self._back
         self._g_backlog.set(len(self.db._dirty.get(tag, ())))
-        t0 = time.perf_counter_ns()
         with self.obs.span("state.commit"):
             new = commit(self.db, global_ratings, st, consumer=tag,
-                         mesh=self.mesh)
+                         mesh=self.mesh, obs=self.obs)
         self._back, self._front = self._front, (new, tag)
-        self._h_commit_us.observe((time.perf_counter_ns() - t0) / 1e3)
         self._m_swaps.inc()
         return self.front
 

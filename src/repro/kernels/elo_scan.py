@@ -130,6 +130,7 @@ def elo_scan_select_pallas(ratings, a_idx, b_idx, outcome, valid,
         out_shape=[jax.ShapeDtypeStruct((m, qp), jnp.float32),
                    jax.ShapeDtypeStruct((1, qp), jnp.int32)],
         interpret=interpret,
+        name="eagle_elo_replay_select",
     )(*args, col(global_ratings), col(costs), bud)
     return out[:, :q].T, choices[0, :q]
 
@@ -149,5 +150,6 @@ def elo_scan_pallas(ratings, a_idx, b_idx, outcome, valid, *, k: float = 32.0,
         out_specs=pl.BlockSpec((m, block_q), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, qp), jnp.float32),
         interpret=interpret,
+        name="eagle_elo_replay",
     )(*args)
     return out[:, :q].T

@@ -104,15 +104,25 @@ def retrieve_replay_pipeline(similarity_fn, replay_fn, q, emb, model_a,
 
     replay_fn may return either `local` or a `(local, *extras)` tuple
     (the fused budget-selection epilogue returns `(local, choices)`);
-    extras are appended to the pipeline's return tuple."""
-    scores = similarity_fn(q, emb)
-    live = jnp.arange(emb.shape[0]) < size
-    scores = jnp.where(live[None, :], scores, -jnp.inf)
-    top_s, top_i = jax.lax.top_k(scores, n)
-    hit = jnp.isfinite(top_s)
-    a, b, s, v = gather_records(model_a, model_b, outcome, valid, top_i, hit)
-    init = jnp.broadcast_to(init_ratings, (q.shape[0], init_ratings.shape[-1]))
-    out = replay_fn(init, a, b, s, v)
+    extras are appended to the pipeline's return tuple.
+
+    Each stage runs under a named scope (`eagle.similarity`,
+    `eagle.topk`, `eagle.gather`, `eagle.replay`), so its device ops
+    carry a stable name in a profile whatever their shapes."""
+    with jax.named_scope("eagle.similarity"):
+        scores = similarity_fn(q, emb)
+    with jax.named_scope("eagle.topk"):
+        live = jnp.arange(emb.shape[0]) < size
+        scores = jnp.where(live[None, :], scores, -jnp.inf)
+        top_s, top_i = jax.lax.top_k(scores, n)
+    with jax.named_scope("eagle.gather"):
+        hit = jnp.isfinite(top_s)
+        a, b, s, v = gather_records(model_a, model_b, outcome, valid,
+                                    top_i, hit)
+    with jax.named_scope("eagle.replay"):
+        init = jnp.broadcast_to(init_ratings,
+                                (q.shape[0], init_ratings.shape[-1]))
+        out = replay_fn(init, a, b, s, v)
     local, extras = (out[0], tuple(out[1:])) if isinstance(out, tuple) \
         else (out, ())
     return (local, top_i, top_s) + extras
@@ -167,30 +177,37 @@ def sharded_retrieve_replay_pipeline(similarity_fn, replay_fn, q, emb,
     column's D-accumulation untouched, and the merge's (shard, local
     rank) pool order reproduces single-device top_k tie-breaking under
     the contiguous partition (see shard_merge_topk). Like the
-    unsharded glue, both backends share this ONE copy."""
+    unsharded glue, both backends share this ONE copy, under the same
+    stage scopes plus `eagle.merge` for the all-gather and merge."""
     from repro.kernels.similarity_topk import (shard_local_topk,
                                                shard_merge_topk)
-    scores = similarity_fn(q, emb)
+    with jax.named_scope("eagle.similarity"):
+        scores = similarity_fn(q, emb)
     c_local = emb.shape[0]
     offset = jax.lax.axis_index(axis_name) * c_local
-    live = (jnp.arange(c_local) + offset) < size
-    scores = jnp.where(live[None, :], scores, -jnp.inf)
-    loc_s, loc_i = shard_local_topk(scores, n)
-    records = tuple(jnp.take(x, loc_i, axis=0)
-                    for x in (model_a, model_b, outcome, valid))
-    top_s, top_i, (ca, cb, cs, cv) = shard_merge_topk(
-        loc_s, loc_i + offset, records, n, axis_name)
-    hit = jnp.isfinite(top_s)
+    with jax.named_scope("eagle.topk"):
+        live = (jnp.arange(c_local) + offset) < size
+        scores = jnp.where(live[None, :], scores, -jnp.inf)
+        loc_s, loc_i = shard_local_topk(scores, n)
+    with jax.named_scope("eagle.gather"):
+        records = tuple(jnp.take(x, loc_i, axis=0)
+                        for x in (model_a, model_b, outcome, valid))
+    with jax.named_scope("eagle.merge"):
+        top_s, top_i, (ca, cb, cs, cv) = shard_merge_topk(
+            loc_s, loc_i + offset, records, n, axis_name)
     nq = q.shape[0]
-    # farthest-first flatten of the MERGED candidates — gather_records'
-    # replay-order contract, minus the row gather it already did
-    a = jnp.flip(ca, axis=1).reshape(nq, -1)
-    b = jnp.flip(cb, axis=1).reshape(nq, -1)
-    s = jnp.flip(cs, axis=1).reshape(nq, -1)
-    v = (jnp.flip(cv, axis=1)
-         & jnp.flip(hit, axis=1)[..., None]).reshape(nq, -1)
-    init = jnp.broadcast_to(init_ratings, (nq, init_ratings.shape[-1]))
-    out = replay_fn(init, a, b, s, v)
+    with jax.named_scope("eagle.replay"):
+        hit = jnp.isfinite(top_s)
+        # farthest-first flatten of the MERGED candidates —
+        # gather_records' replay-order contract, minus the row gather
+        # it already did
+        a = jnp.flip(ca, axis=1).reshape(nq, -1)
+        b = jnp.flip(cb, axis=1).reshape(nq, -1)
+        s = jnp.flip(cs, axis=1).reshape(nq, -1)
+        v = (jnp.flip(cv, axis=1)
+             & jnp.flip(hit, axis=1)[..., None]).reshape(nq, -1)
+        init = jnp.broadcast_to(init_ratings, (nq, init_ratings.shape[-1]))
+        out = replay_fn(init, a, b, s, v)
     local, extras = (out[0], tuple(out[1:])) if isinstance(out, tuple) \
         else (out, ())
     return (local, top_i, top_s) + extras

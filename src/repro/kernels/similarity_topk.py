@@ -50,6 +50,7 @@ def similarity_pallas(q, db, *, block_q: int = 128, block_n: int = 256,
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn + pq, n + pn), jnp.float32),
         interpret=interpret,
+        name="eagle_similarity",
     )(qp, dbp)
     return out[:qn, :n]
 

@@ -3,8 +3,9 @@
 Three instruments behind one bundle:
 
   * `SpanTracer`   — host-side span timing, ring-buffered, Chrome-trace
-                     export, optional `jax.profiler.TraceAnnotation`
-                     pass-through (obs/trace.py);
+                     export, and a `jax.profiler.TraceAnnotation` for
+                     every span while a profiler session is active
+                     (obs/trace.py);
   * `MetricsRegistry` — counters / gauges / fixed-bucket histograms with
                      Prometheus-text and JSON exposition (obs/metrics.py);
   * `EventLog`     — structured JSONL event stream (per-request route
@@ -12,10 +13,12 @@ Three instruments behind one bundle:
 
 Gating contract: METRICS ARE ALWAYS ON — they back typed engine
 statistics (`ServingEngine.stats`) and cost nanoseconds per batch.
-SPANS and EVENTS are gated by `Observability.enabled` (default OFF):
-when disabled, an instrumented region costs one attribute check, which
-is how the <5% hot-path overhead budget is enforced (ci.sh
---assert-obs measures the ENABLED path against that budget too).
+The span RING and EVENTS are gated by `Observability.enabled` (default
+OFF); profiler annotations are not: any active profiler session sees
+every span, enabled or not. Outside a session a disabled span costs
+one attribute check and one profiler-state query, which is how the <5%
+hot-path overhead budget is enforced (ci.sh --assert-obs measures the
+ENABLED path against that budget too).
 
 Components take an optional `obs=` handle and fall back to the module
 default (`DEFAULT`), so a process normally has one telemetry scope;
@@ -30,7 +33,7 @@ from repro.obs.events import EventLog
 from repro.obs.metrics import (DEFAULT_LATENCY_BOUNDS_US, Counter, Gauge,
                                Histogram, MetricsRegistry,
                                geometric_bounds)
-from repro.obs.trace import NULL_SPAN, SpanTracer, named_scope
+from repro.obs.trace import NULL_SPAN, SpanTracer, annotation, named_scope
 
 __all__ = ["Observability", "DEFAULT", "get_obs", "enable", "disable",
            "reset_default", "SpanTracer", "MetricsRegistry", "EventLog",
@@ -43,18 +46,16 @@ class Observability:
     switch for the gated instruments."""
 
     def __init__(self, enabled: bool = False, trace_capacity: int = 8192,
-                 event_capacity: int = 1 << 16, xprof: bool = False,
+                 event_capacity: int = 1 << 16,
                  event_path: Optional[str] = None):
-        self.tracer = SpanTracer(capacity=trace_capacity, xprof=xprof)
+        self.tracer = SpanTracer(capacity=trace_capacity)
         self.registry = MetricsRegistry()
         self.events = EventLog(capacity=event_capacity, path=event_path)
         self.tracer.enabled = enabled
         self.enabled = enabled
 
     # -- switches ------------------------------------------------------------
-    def enable(self, xprof: Optional[bool] = None) -> "Observability":
-        if xprof is not None:
-            self.tracer.xprof = xprof
+    def enable(self) -> "Observability":
         self.tracer.enabled = True
         self.enabled = True
         return self
@@ -65,11 +66,13 @@ class Observability:
         return self
 
     # -- hot-path helpers ----------------------------------------------------
-    def span(self, name: str):
-        """Timed span; collapses to a shared no-op when disabled."""
-        if not self.enabled:
-            return NULL_SPAN
-        return self.tracer.span(name)
+    def span(self, name: str, ring: bool = True):
+        """Timed span: into the ring when enabled and `ring`, into any
+        active profiler session always; a shared no-op otherwise.
+        `ring=False` marks per-iteration regions (profiler only)."""
+        if self.enabled:
+            return self.tracer.span(name, ring)
+        return annotation(name)
 
     def emit(self, record) -> bool:
         """Gated event emission; returns whether the record was taken."""
@@ -108,9 +111,9 @@ def reset_default(enabled: bool = False, **kw) -> Observability:
     return DEFAULT
 
 
-def enable(xprof: Optional[bool] = None) -> Observability:
-    """Switch the process-default scope on (spans + events)."""
-    return DEFAULT.enable(xprof=xprof)
+def enable() -> Observability:
+    """Switch the process-default scope on (span ring + events)."""
+    return DEFAULT.enable()
 
 
 def disable() -> Observability:

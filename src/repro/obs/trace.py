@@ -13,14 +13,18 @@ Design constraints (DESIGN.md §9):
     orderable within the process even across NTP steps;
   * export is Chrome-trace JSON (the `traceEvents` "X" complete-event
     form), which chrome://tracing and Perfetto both load;
-  * when `xprof=True`, every span also enters a
-    `jax.profiler.TraceAnnotation`, so host spans line up with the
-    device timeline in an XLA profile. `named_scope` is re-exported
-    for annotating code INSIDE jitted functions (it tags HLO ops, not
-    wall time).
+  * whenever a profiler session is active (`jax.profiler.start_trace`
+    or a profiler server capture), every span also enters a
+    `jax.profiler.TraceAnnotation`, enabled or not, so host spans sit
+    on the device trace's own clock. The ring stays gated by
+    `enabled`; `span(name, ring=False)` is a profiler-only marker for
+    per-iteration regions that must never fill the ring.
+    `named_scope` is re-exported for annotating code INSIDE jitted
+    functions (it tags HLO ops, not wall time).
 
-A disabled tracer hands out a shared no-op span: the cost of an
-instrumented region collapses to one attribute check + one call.
+Outside a profiler session a disabled span (or a ring-free marker)
+is the shared no-op span: the cost of an instrumented region
+collapses to one attribute check and one profiler-state query.
 """
 from __future__ import annotations
 
@@ -33,8 +37,12 @@ from typing import Dict, List, Optional, Tuple
 
 try:  # pass-throughs to the XLA profiler (absent on exotic builds)
     from jax.profiler import TraceAnnotation
+    profiling = TraceAnnotation.is_enabled
 except ImportError:  # pragma: no cover
     TraceAnnotation = None
+
+    def profiling() -> bool:
+        return False
 try:
     from jax import named_scope  # noqa: F401  (re-export)
 except ImportError:  # pragma: no cover
@@ -61,6 +69,12 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def annotation(name: str):
+    """Profiler-only span: a TraceAnnotation while a profiler session
+    is active, the shared no-op span otherwise."""
+    return TraceAnnotation(name) if profiling() else NULL_SPAN
+
+
 class _Span:
     __slots__ = ("tracer", "name", "t0", "depth", "annot")
 
@@ -74,7 +88,7 @@ class _Span:
         depth = getattr(tls, "depth", 0)
         tls.depth = depth + 1
         self.depth = depth
-        if tr.xprof and TraceAnnotation is not None:
+        if profiling():
             self.annot = TraceAnnotation(self.name)
             self.annot.__enter__()
         else:
@@ -98,21 +112,21 @@ class _Span:
 class SpanTracer:
     """Thread-safe span recorder over a preallocated ring buffer."""
 
-    def __init__(self, capacity: int = 8192, xprof: bool = False):
+    def __init__(self, capacity: int = 8192):
         assert capacity > 0
         self.capacity = capacity
-        self.xprof = xprof
         self.enabled = True
         self._slots: List[Optional[SpanRecord]] = [None] * capacity
         self._seq = itertools.count()
         self._tls = threading.local()
 
     # -- recording -----------------------------------------------------------
-    def span(self, name: str):
-        """Context manager timing a region; no-op when disabled."""
-        if not self.enabled:
-            return NULL_SPAN
-        return _Span(self, name)
+    def span(self, name: str, ring: bool = True):
+        """Context manager timing a region into the ring (when enabled
+        and `ring`); a profiler annotation only, or a no-op, otherwise."""
+        if ring and self.enabled:
+            return _Span(self, name)
+        return annotation(name)
 
     # -- accounting ----------------------------------------------------------
     @property

@@ -62,6 +62,10 @@ class Response:
 class FleetModel:
     """One servable model: jitted prefill + decode with greedy sampling."""
 
+    #: telemetry scope; None -> the module default (repro.obs.DEFAULT).
+    #: ServingEngine points this at its own scope.
+    obs: Optional[OBS.Observability] = None
+
     def __init__(self, cfg: ModelConfig, seed: int = 0,
                  max_len: int = 128):
         self.cfg = cfg
@@ -74,25 +78,41 @@ class FleetModel:
             lambda p, c, t, i: T.decode_step(cfg, p, c, t, i))
 
     def generate(self, tokens: np.ndarray, max_new: int) -> np.ndarray:
-        """tokens: (B, S) -> (B, max_new) greedy continuation."""
+        """tokens: (B, S) -> (B, max_new) greedy continuation.
+
+        Spans on `self.obs`, once per call: `serve.prefill.<model>` (the
+        prompt's upload, prefill and its token on the host) and
+        `serve.decode.<model>` (the decode loop). Each decode step is a
+        ring-free marker
+        `serve.decode_step.<model>` around the decode and
+        `serve.readout.<model>`, the host sync of the step's token."""
+        ob = OBS.get_obs(self.obs)
+        name = self.cfg.name
+        step_span, readout_span = (f"serve.decode_step.{name}",
+                                   f"serve.readout.{name}")
         b, s = tokens.shape
-        batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
-        if self.cfg.arch_type == "encdec":
-            batch["enc_embeds"] = jnp.zeros(
-                (b, self.cfg.n_audio_frames, self.cfg.d_model), jnp.float32)
-        if self.cfg.arch_type == "vlm":
-            batch["img_embeds"] = jnp.zeros(
-                (b, self.cfg.n_image_tokens, self.cfg.d_model), jnp.float32)
-            s += self.cfg.n_image_tokens
-        logits, cache = self._prefill(self.params, batch)
-        outs = []
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-        for i in range(max_new):
-            outs.append(np.asarray(tok)[:, 0])
-            if i == max_new - 1:
-                break
-            logits, cache = self._decode(self.params, cache, tok, s + i)
+        with ob.span(f"serve.prefill.{name}"):
+            batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
+            if self.cfg.arch_type == "encdec":
+                batch["enc_embeds"] = jnp.zeros(
+                    (b, self.cfg.n_audio_frames, self.cfg.d_model),
+                    jnp.float32)
+            if self.cfg.arch_type == "vlm":
+                batch["img_embeds"] = jnp.zeros(
+                    (b, self.cfg.n_image_tokens, self.cfg.d_model),
+                    jnp.float32)
+                s += self.cfg.n_image_tokens
+            logits, cache = self._prefill(self.params, batch)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+            outs = [np.asarray(tok)[:, 0]]
+        with ob.span(f"serve.decode.{name}"):
+            for i in range(max_new - 1):
+                with ob.span(step_span, ring=False):
+                    logits, cache = self._decode(self.params, cache, tok,
+                                                 s + i)
+                    tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+                    with ob.span(readout_span, ring=False):
+                        outs.append(np.asarray(tok)[:, 0])
         return np.stack(outs, axis=1)
 
 
@@ -131,9 +151,12 @@ class ServingEngine:
         self.quality_oracle = quality_oracle  # (emb, model_idx) -> quality
         # one telemetry scope threads through every layer the engine
         # owns: dispatcher spans/metrics, double-buffer commit stats,
-        # router feedback magnitude, and the engine's own serve spans
+        # router feedback magnitude, the fleet's prefill/decode spans,
+        # and the engine's own serve spans
         self.obs = OBS.get_obs(obs)
         router.obs = self.obs
+        for m in fleet.values():
+            m.obs = self.obs
         # decision-log clock: injectable (matching AdmissionQueue's
         # now_ns) so traffic replays produce deterministic /decisions
         # output; defaults to wall time, which is what
@@ -173,16 +196,6 @@ class ServingEngine:
             m: r.counter("serve_model_requests_total",
                          "requests served per fleet model", model=m)
             for m in fleet}
-        self._g_queue = r.gauge("serve_queue_depth",
-                                "requests in the current serve() batch")
-        self._h_route = r.histogram("serve_route_us",
-                                    "routing latency per batch")
-        self._h_generate = r.histogram("serve_generate_us",
-                                       "per-model-group generate latency")
-        self._h_feedback = r.histogram("serve_feedback_us",
-                                       "feedback append+ELO-fold latency")
-        self._h_commit = r.histogram("serve_commit_us",
-                                     "double-buffer commit latency")
         self._sorted_costs = np.sort(np.asarray(router.costs, np.float32))
         if warmup_batch_sizes is not None:
             self.warmup(warmup_batch_sizes)
@@ -239,7 +252,6 @@ class ServingEngine:
             return []   # np.stack below rejects empty lists
         obs = self.obs
         self._m_steps.inc()
-        self._g_queue.set(len(requests))
         with obs.span("serve.step"):
             t0 = time.perf_counter()
             embs = np.stack([r.embedding for r in requests])
@@ -252,7 +264,6 @@ class ServingEngine:
                 choices = self.dispatch.route(self.dbuf.front, embs,
                                               budgets)
             route_dt = time.perf_counter() - t0
-            self._h_route.observe(route_dt * 1e6)
             if obs.enabled:
                 self._emit_decisions(requests, budgets, choices)
                 if self.quality is not None:
@@ -282,9 +293,7 @@ class ServingEngine:
                 tg = time.perf_counter()
                 with obs.span(f"serve.generate.{name}"):
                     gen = self.fleet[name].generate(toks, max_new)
-                gen_dt = time.perf_counter() - tg
-                self._h_generate.observe(gen_dt * 1e6)
-                dt = route_dt + gen_dt
+                dt = route_dt + time.perf_counter() - tg
                 for row, i in enumerate(sel):
                     responses[i] = Response(
                         requests[i].rid, name,
@@ -310,20 +319,14 @@ class ServingEngine:
                                      for i, bi in zip(idxs, b)])
                     outcome = np.where(qa == qb, 0.5,
                                        (qa > qb).astype(np.float32))
-                    tf = time.perf_counter()
                     with obs.span("serve.feedback"):
                         self.router.feedback(embs[idxs], a, b, outcome)
-                    self._h_feedback.observe(
-                        (time.perf_counter() - tf) * 1e6)
                     self._m_feedback.inc(int(idxs.size))
                     # absorb the new rows into the BACK buffer and swap
                     # — async, so it overlaps anything still in flight
                     # on the old front (double-buffered commit protocol)
-                    tc = time.perf_counter()
                     with obs.span("serve.commit"):
                         self.dbuf.commit(self.router.global_ratings)
-                    self._h_commit.observe(
-                        (time.perf_counter() - tc) * 1e6)
                     self._m_commits.inc()
                     if self.prebaker is not None:
                         self.prebaker.poll()

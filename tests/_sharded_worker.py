@@ -19,6 +19,8 @@ single JSON report line covering the whole matrix —
     feedback+commit cycles: an empty-ledger commit never exercises the
     scatter, so counting before the first real cycle would charge its
     compile to the steady state);
+  * the named device scopes of the 4-shard route (both backends) and
+    of the owner-scatter, in their lowered HLO;
   * a seeded property-style table the parent replays through the
     hypothesis shim.
 """
@@ -179,6 +181,29 @@ def main():
                 st = STATE.commit(db3, ratings, st, mesh=mesh)
             jax.block_until_ready(st)
         report["hot_compiles"][str(s)] = cc.count
+
+    # -- named device scopes in the 4-shard programs ---------------------
+    mesh = meshes[4]
+    sstate = STATE.shard_state(state, mesh)
+    report["scopes"] = {}
+    for bk in BACKENDS:
+        txt = STATE.route_batch_choices_sharded.lower(
+            sstate, rep(mesh, q), rep(mesh, budgets), rep(mesh, costs),
+            mesh=mesh, backend=bk).as_text(debug_info=True)
+        report["scopes"][bk] = sorted(
+            sc for sc in ("eagle.similarity", "eagle.topk", "eagle.gather",
+                          "eagle.merge", "eagle.replay") if sc in txt)
+    rows = np.zeros((4, 2), np.int32)
+    shr = NamedSharding(mesh, P(STATE.SHARD.DB_AXIS))
+    put = lambda x: jax.device_put(x, shr)  # noqa: E731
+    txt = STATE._sharded_scatter(mesh).lower(
+        sstate.emb, sstate.model_a, sstate.model_b, sstate.outcome,
+        sstate.valid, put(rows.reshape(-1)),
+        put(np.zeros((8, D), np.float32)), put(np.zeros((8, RCAP), np.int32)),
+        put(np.zeros((8, RCAP), np.int32)),
+        put(np.zeros((8, RCAP), np.float32)),
+        put(np.zeros((8, RCAP), bool))).as_text(debug_info=True)
+    report["scopes"]["commit"] = "eagle.commit_scatter" in txt
 
     # -- seeded property-style table (replayed via the shim) -------------
     report["seeded"] = {}

@@ -94,3 +94,14 @@ def test_seeded_random_batches_match_oracle(seed):
     worker computes the seeded table; the shim (or real hypothesis)
     replays every seed here."""
     assert report()["seeded"][str(int(seed))] is True
+
+
+def test_sharded_programs_carry_the_stage_scopes():
+    """Each stage of the 4-shard route, the cross-shard merge among
+    them, and the owner-scatter lower under their named scopes."""
+    scopes = report()["scopes"]
+    for bk in ("reference", "pallas_interpret"):
+        assert scopes[bk] == sorted(["eagle.similarity", "eagle.topk",
+                                     "eagle.gather", "eagle.merge",
+                                     "eagle.replay"]), bk
+    assert scopes["commit"] is True
