@@ -43,6 +43,7 @@ from repro.core import state as STATE
 from repro.core.state import (RouterState, route_batch_choices,
                               route_batch_choices_sharded,
                               state_shardings)
+from repro.kernels.similarity_topk import two_stage_topk
 
 #: default bucket ladder bounds (powers of two, inclusive)
 MIN_BUCKET = 8
@@ -201,6 +202,8 @@ class RouteDispatcher:
         self.min_bucket = min_bucket
         self.max_bucket = max_bucket
         self._cache: Dict[Tuple, jax.stages.Compiled] = {}
+        # per cache key: whether that executable's top-k is two-stage
+        self._two_stage: Dict[Tuple, bool] = {}
         self._lock = threading.Lock()
         self.stats = DispatchStats()
         _ensure_listener()
@@ -216,6 +219,9 @@ class RouteDispatcher:
         self._m_padded = r.counter(
             "dispatch_padded_rows_total",
             "bucket-padded rows dispatched (>= rows; waste = padded-rows)")
+        self._m_two_stage = r.counter(
+            "dispatch_two_stage_topk_total",
+            "dispatches whose executable takes the two-stage top-k")
         self._m_hits = r.counter(
             "dispatch_cache_hits_total", "executable-cache hits")
         self._m_misses = r.counter(
@@ -291,6 +297,7 @@ class RouteDispatcher:
                             state, q, b, c, mesh=self.mesh,
                             **self.kw).compile()
                 self._cache[key] = fn
+                self._two_stage[key] = self._takes_two_stage_topk(state)
                 self.stats.misses += 1
                 self.stats.warmed += bool(warm)
                 dt = time.perf_counter() - t0
@@ -302,6 +309,17 @@ class RouteDispatcher:
                                "records": state.records_per_query,
                                "seconds": dt})
         return fn
+
+    def _takes_two_stage_topk(self, state: RouterState) -> bool:
+        """Whether the route executable for `state`'s shapes reduces its
+        score panel with the two-stage top-k: a static function of the
+        rows per shard and the neighbours kept (panel_topk)."""
+        if self.kw["mode"] == "global":
+            return False                # no retrieval
+        shards = 1 if self.mesh is None else SHARD.db_shard_count(self.mesh)
+        c_local = state.capacity // shards
+        n = min(self.kw["n_neighbors"], state.capacity, c_local)
+        return two_stage_topk(c_local, n)
 
     def warmup(self, state: RouterState,
                batch_sizes: Optional[Sequence[int]] = None) -> int:
@@ -389,7 +407,10 @@ class RouteDispatcher:
                 q = jax.device_put(q, self._rep)
                 b = jax.device_put(b, self._rep)
         with obs.span("dispatch.launch"):
-            return self._compiled(state, qb)(state, q, b, self.costs), nq
+            res = self._compiled(state, qb)(state, q, b, self.costs)
+        if self._two_stage[self._key(state, qb)]:
+            self._m_two_stage.inc()
+        return res, nq
 
     def _route_one(self, state: RouterState, q: np.ndarray,
                    b: np.ndarray) -> np.ndarray:

@@ -6,6 +6,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.similarity_topk import panel_topk
+
 
 def similarity_ref(q, db):
     """Cosine-similarity score panel. q: (Q,D), db: (N,D) — both rows are
@@ -98,9 +100,10 @@ def retrieve_replay_pipeline(similarity_fn, replay_fn, q, emb, model_a,
                              model_b, outcome, valid, size, init_ratings,
                              *, n):
     """The fused retrieval chain — similarity panel -> live-row masked
-    top-k -> farthest-first record gather -> replay from the broadcast
-    prior — with the stage implementations injected, so the reference
-    and Pallas backends share ONE copy of the glue and cannot drift.
+    top-k (panel_topk) -> farthest-first record gather -> replay from
+    the broadcast prior — with the stage implementations injected, so
+    the reference and Pallas backends share ONE copy of the glue and
+    cannot drift.
 
     replay_fn may return either `local` or a `(local, *extras)` tuple
     (the fused budget-selection epilogue returns `(local, choices)`);
@@ -112,9 +115,7 @@ def retrieve_replay_pipeline(similarity_fn, replay_fn, q, emb, model_a,
     with jax.named_scope("eagle.similarity"):
         scores = similarity_fn(q, emb)
     with jax.named_scope("eagle.topk"):
-        live = jnp.arange(emb.shape[0]) < size
-        scores = jnp.where(live[None, :], scores, -jnp.inf)
-        top_s, top_i = jax.lax.top_k(scores, n)
+        top_s, top_i = panel_topk(scores, n, size)
     with jax.named_scope("eagle.gather"):
         hit = jnp.isfinite(top_s)
         a, b, s, v = gather_records(model_a, model_b, outcome, valid,
@@ -166,8 +167,9 @@ def sharded_retrieve_replay_pipeline(similarity_fn, replay_fn, q, emb,
     arrive as this shard's CONTIGUOUS row range, the queries and the
     replay prior arrive replicated. Stages:
 
-      local similarity panel -> global-row live mask -> local top
-      min(n, C_local) -> local candidate-record gather ->
+      local similarity panel -> local top min(n, C_local) of the
+      rows live by their global index (shard_local_topk) -> local
+      candidate-record gather ->
       cross-shard merge (all-gather + final top-n reduce, candidates'
       records carried by position) -> farthest-first flatten ->
       replicated replay + epilogue.
@@ -186,9 +188,7 @@ def sharded_retrieve_replay_pipeline(similarity_fn, replay_fn, q, emb,
     c_local = emb.shape[0]
     offset = jax.lax.axis_index(axis_name) * c_local
     with jax.named_scope("eagle.topk"):
-        live = (jnp.arange(c_local) + offset) < size
-        scores = jnp.where(live[None, :], scores, -jnp.inf)
-        loc_s, loc_i = shard_local_topk(scores, n)
+        loc_s, loc_i = shard_local_topk(scores, n, size, offset)
     with jax.named_scope("eagle.gather"):
         records = tuple(jnp.take(x, loc_i, axis=0)
                         for x in (model_a, model_b, outcome, valid))

@@ -1,14 +1,53 @@
-"""Pallas TPU kernel: fused L2-normalize + cosine-similarity score panel.
+"""Pallas TPU kernel: fused L2-normalize + cosine-similarity score panel,
+and the exact top-k that reduces the panel (DESIGN.md §3).
 
-The router retrieval hot spot (DESIGN.md §3): queries x vector-DB scores.
-The DB is streamed HBM->VMEM in (block_n, D) panels; the query block stays
+The router retrieval hot spot: queries x vector-DB scores. The DB is
+streamed HBM->VMEM in (block_n, D) panels; the query block stays
 resident; the MXU computes the (block_q, D)x(D, block_n) panel with the
-row normalization fused in VMEM. Top-k over the panel is left to
-jax.lax.top_k (data-dependent sorts map poorly onto the VPU — see ops.py).
+row normalization fused in VMEM. The kernel's result is the one f32
+panel; the top-k over it is ordinary XLA (data-dependent sorts map
+poorly onto the VPU — see ops.py).
 
 Blocks are MXU-aligned (multiples of 128 on the matmul dims); D is kept
 whole per panel (1536 floats/row ~ 6 KiB: a 256-row panel is 1.5 MiB,
 comfortably inside the ~16 MiB VMEM budget together with the query block).
+
+Top-k over the panel (`panel_topk`). `lax.top_k` over a (Q, C) panel
+with C = 2^20 runs at about 15x the time of one read of the panel, so a
+wide panel takes an exact two-stage top-k instead:
+
+  1. chunk maxima: the max of each chunk of TOPK_CHUNK consecutive rows,
+     the live-row mask fused into the reduce, so the panel is read once
+     and no masked copy is written;
+  2. chunk selection: `lax.top_k` over the (Q, C/B) maxima, the n chunk
+     ids sorted ascending;
+  3. candidate gather: the n chunks' B rows each, (Q, n·B), live-masked
+     again from their row ids;
+  4. final top-k: `lax.top_k` over the candidates, positions mapped back
+     to row ids.
+
+Exactness. Order (score, row) pairs by score descending, then row
+ascending: `lax.top_k` returns the first n pairs of that order. A chunk
+holding one of them has a best pair at least as high, and the chunk
+ranking of stage 2 (max descending, then chunk id ascending) is that
+order of the chunks' best pairs, because chunks are contiguous. At most
+n chunks have a best pair at or above the n-th winner, each such pair
+being a distinct winner; so the n best chunks hold all n winners, ties
+and dead (-inf) rows included. The candidates are laid out in ascending
+row order, so the final `lax.top_k` breaks ties toward the lowest row as
+the one-stage top-k does: scores and row ids are bitwise equal to it.
+Scores are assumed free of NaN.
+
+Shape rule, static: the two-stage path runs when C % B == 0 and
+C >= 4·n·B (10,240 rows at n = 20); below that the candidates are a
+large share of the panel and one `lax.top_k` does as well.
+
+Layout. The TPU tiles an f32 (Q, C) array in (8, 128) blocks, so its
+bytes are laid out as (Q/8, C/128, 8, 128). Both panel-sized stages
+index the panel through exactly that view (a bitcast there): the
+chunk-max reduce runs over its lane axis and the gather takes whole
+(1, 128) tile rows. A (Q, C/B, B) reshape is not a bitcast under that
+tiling, and XLA would materialise a 1 GiB relayout copy for it.
 """
 from __future__ import annotations
 
@@ -56,16 +95,68 @@ def similarity_pallas(q, db, *, block_q: int = 128, block_n: int = 256,
 
 
 # ---------------------------------------------------------------------------
+# exact two-stage top-k over the score panel
+# ---------------------------------------------------------------------------
+
+#: rows per chunk of the two-stage top-k: one lane width, so a chunk is
+#: one row of an (8, 128) tile. XLA reduces a tile row at a time either
+#: way, so a wider chunk only adds a second reduce and candidates: on a
+#: TPU v5e, (256, 2^20) panel, 128 took 2.32 ms and 256 took 2.52 ms
+#: (one-stage lax.top_k 23.65 ms with its mask copy)
+TOPK_CHUNK = 128
+#: the two-stage path needs at least this many candidates' worth of rows
+_MIN_CHUNKS_PER_N = 4
+
+
+def two_stage_topk(c: int, n: int) -> bool:
+    """Whether panel_topk reduces a (Q, c) panel to its top n in two
+    stages. Both are static shapes, so the choice is made at trace
+    time."""
+    return c % TOPK_CHUNK == 0 and c >= _MIN_CHUNKS_PER_N * n * TOPK_CHUNK
+
+
+def panel_topk(scores, n: int, size, offset=0):
+    """Top n of each row of the (Q, C) score panel over the live rows:
+    panel column j is row j + offset, live while below `size`; dead rows
+    score -inf. Returns (top_scores (Q, n), top_rows (Q, n)) with rows
+    local to the panel, bitwise equal to `lax.top_k` over the masked
+    panel (module docstring: two stages when two_stage_topk(C, n))."""
+    q, c = scores.shape
+    if not two_stage_topk(c, n):
+        live = (jnp.arange(c) + offset) < size
+        return jax.lax.top_k(jnp.where(live[None, :], scores, -jnp.inf), n)
+    b = TOPK_CHUNK
+    sub = 8 if q % 8 == 0 else 1        # the tile's rows of queries
+    # (Q/8, C/B, 8, B): the panel's bytes in the TPU's tiled order, a
+    # chunk being one tile row
+    tiles = scores.reshape(q // sub, sub, c // b, b).transpose(0, 2, 1, 3)
+    row = (jax.lax.broadcasted_iota(jnp.int32, tiles.shape, 1) * b
+           + jax.lax.broadcasted_iota(jnp.int32, tiles.shape, 3))
+    maxima = jnp.where(row + offset < size, tiles, -jnp.inf).max(axis=3)
+    maxima = maxima.transpose(0, 2, 1).reshape(q, c // b)
+    _, chunk = jax.lax.top_k(maxima, n)
+    chunk = jnp.sort(chunk, axis=1)     # candidates in ascending row order
+    qi = jnp.arange(q)[:, None]
+    cand = tiles[qi // sub, chunk, qi % sub].reshape(q, n * b)
+    rows = (chunk[:, :, None] * b + jnp.arange(b)).reshape(q, n * b)
+    cand = jnp.where(rows + offset < size, cand, -jnp.inf)
+    top_s, pos = jax.lax.top_k(cand, n)
+    return top_s, jnp.take_along_axis(rows, pos, axis=1)
+
+
+# ---------------------------------------------------------------------------
 # capacity-sharded retrieval: local top-k + cross-shard merge (DESIGN.md §12)
 # ---------------------------------------------------------------------------
 
-def shard_local_topk(scores, n: int):
-    """Per-shard candidate reduce over a LOCAL score panel (Q, C_l):
-    keep min(n, C_l) candidates. That per-shard k is exact — any single
-    shard can contribute at most min(n, C_l) rows of the global top-n,
-    so the merged pool provably contains the true global top-n.
-    Returns (top_scores (Q, kl), top_local_idx (Q, kl))."""
-    return jax.lax.top_k(scores, min(n, scores.shape[-1]))
+def shard_local_topk(scores, n: int, size, offset):
+    """Per-shard candidate reduce over a LOCAL score panel (Q, C_l)
+    whose first row is global row `offset`: keep min(n, C_l) candidates,
+    rows at or past the global live `size` scoring -inf. That per-shard
+    k is exact — any single shard can contribute at most min(n, C_l)
+    rows of the global top-n, so the merged pool provably contains the
+    true global top-n. Returns (top_scores (Q, kl), top_local_idx
+    (Q, kl))."""
+    return panel_topk(scores, min(n, scores.shape[-1]), size, offset)
 
 
 def shard_merge_topk(top_s, top_i, payloads, n: int, axis_name: str):

@@ -21,6 +21,11 @@ single JSON report line covering the whole matrix —
     compile to the steady state);
   * the named device scopes of the 4-shard route (both backends) and
     of the owner-scatter, in their lowered HLO;
+  * a DB wide enough that every shard's top-k takes two stages
+    (kernels/similarity_topk.py panel_topk), through the dispatcher on
+    each mesh and both backends: bitwise equal to one device, with
+    dispatch_two_stage_topk_total counting every dispatch there and
+    none at the small capacity above;
   * a seeded property-style table the parent replays through the
     hypothesis shim.
 """
@@ -30,6 +35,8 @@ import sys
 import numpy as np
 
 M, D, CAP, RCAP = 4, 16, 128, 6
+#: 2^14 rows a shard on 4 shards: two-stage top-k on every mesh
+WIDE_CAP, WIDE_LIVE = 1 << 16, 50_001
 MESHES = (1, 2, 4)
 MODES = ("combined", "global", "local")
 BACKENDS = ("reference", "pallas_interpret")
@@ -204,6 +211,51 @@ def main():
         put(np.zeros((8, RCAP), np.float32)),
         put(np.zeros((8, RCAP), bool))).as_text(debug_info=True)
     report["scopes"]["commit"] = "eagle.commit_scatter" in txt
+
+    # -- wide DB: the two-stage top-k on every shard ----------------------
+    from repro import obs as OBS
+    from repro.core.dispatch import RouteDispatcher
+    from repro.kernels.similarity_topk import two_stage_topk
+
+    def counted(mesh, st, backend, queries, rounds=3):
+        """The last of `rounds` dispatches, and the dispatcher's
+        (dispatch count, two-stage count)."""
+        d = RouteDispatcher(costs, backend=backend, mesh=mesh,
+                            obs=OBS.Observability())
+        out = [d.route_result(st, queries, budgets) for _ in range(rounds)]
+        reg = d.obs.registry
+        return out[-1], (reg.counter("dispatch_calls_total").value,
+                         reg.counter("dispatch_two_stage_topk_total").value)
+
+    rw = np.random.default_rng(300)
+    db_w = VectorDB(D, capacity=WIDE_CAP, records_per_query=RCAP)
+    emb_w = rw.normal(size=(WIDE_LIVE, D)).astype(np.float32)
+    # ties: every shard's first rows repeat the previous shard's last
+    for lo in range(1 << 14, WIDE_LIVE, 1 << 14):
+        emb_w[lo:lo + 3] = emb_w[lo - 3:lo]
+    rec = rw.integers(0, M, (WIDE_LIVE, RCAP)).astype(np.int32)
+    db_w.add_rows(emb_w, rec, (rec + 1) % M,
+                  rw.random((WIDE_LIVE, RCAP)).astype(np.float32).round(),
+                  rw.integers(1, RCAP, WIDE_LIVE))
+    state_w = STATE.state_from_buffer(db_w, ratings)
+    q_w = q.copy()
+    q_w[:2] = emb_w[(1 << 14) - 1], emb_w[(1 << 15) - 2]
+    report["wide"] = {"two_stage_per_shard": {
+        str(s): two_stage_topk(WIDE_CAP // s, 20) for s in MESHES}}
+    for bk in BACKENDS:
+        want = STATE.route_batch_choices(state_w, q_w, budgets, costs,
+                                         backend=bk)
+        for s in MESHES:
+            (ch, topk), calls = counted(
+                meshes[s], STATE.shard_state(state_w, meshes[s]), bk, q_w)
+            report["wide"][f"{s}/{bk}"] = {
+                "equal": bool(np.array_equal(ch, np.asarray(want.choices))
+                              and np.array_equal(
+                                  topk, np.asarray(want.topk_idx))),
+                "calls": calls[0], "two_stage": calls[1]}
+    _, calls = counted(meshes[4], STATE.shard_state(state, meshes[4]),
+                       "reference", q)
+    report["wide"]["narrow"] = {"calls": calls[0], "two_stage": calls[1]}
 
     # -- seeded property-style table (replayed via the shim) -------------
     report["seeded"] = {}

@@ -4,6 +4,8 @@ invariance of choices (raw vs dispatcher-padded, all modes, both
 backends), no-recompile within a bucket, fused choices vs the
 select_within_budget oracle, warmup precompilation, and DoubleBuffer
 equivalence to a full upload."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,7 +17,10 @@ from repro.core.dispatch import (MIN_BUCKET, CapacityPrebaker,
                                  xla_compile_count)
 from repro.core.router import (EagleConfig, EagleRouter, GlobalOnlyRouter,
                                LocalOnlyRouter, select_within_budget)
-from repro.core.state import DoubleBuffer, route_batch, state_from_buffer
+from repro.core.state import (DoubleBuffer, route_batch,
+                              route_batch_choices, state_from_buffer)
+from repro.kernels import ref as kref
+from repro.kernels.similarity_topk import two_stage_topk
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -184,6 +189,90 @@ def test_cache_key_tracks_state_shape():
     ch = d.route(r.state, q, 5.0)
     assert d.cache_stats()["entries"] == 2
     np.testing.assert_array_equal(ch, np.asarray(r.route(q, 5.0)))
+
+
+# ---------------------------------------------------------------------------
+# two-stage top-k through the route (kernels/similarity_topk.py)
+# ---------------------------------------------------------------------------
+
+WIDE_CAPACITY = 1 << 14     # 128 chunks of 128 rows: two stages at N = 20
+
+
+def _one_stage_topk(scores, n, size, offset=0):
+    live = (jnp.arange(scores.shape[1]) + offset) < size
+    return jax.lax.top_k(jnp.where(live[None, :], scores, -jnp.inf), n)
+
+
+@contextlib.contextmanager
+def _traced_with_one_stage_topk():
+    """Route programs traced inside take lax.top_k over the masked
+    panel in place of panel_topk: the oracle of the two-stage path."""
+    jax.clear_caches()
+    saved = kref.panel_topk
+    kref.panel_topk = _one_stage_topk
+    try:
+        yield
+    finally:
+        kref.panel_topk = saved
+        jax.clear_caches()
+
+
+def _wide_router(backend):
+    """12,001 live rows of 2^14 (the live edge inside a chunk); every
+    tenth row repeats an earlier row's embedding, so scores tie, and
+    five of the 13 queries are such rows."""
+    rng = np.random.default_rng(11)
+    n_models, n = 5, 12_001
+    r = EagleRouter([f"m{i}" for i in range(n_models)],
+                    np.arange(1, n_models + 1.0),
+                    EagleConfig(embed_dim=8, backend=backend),
+                    db_capacity=WIDE_CAPACITY)
+    emb = rng.normal(size=(n, 8)).astype(np.float32)
+    emb[10::10] = emb[:len(emb[10::10])]
+    a = rng.integers(0, n_models, n)
+    b = (a + 1 + rng.integers(0, n_models - 1, n)) % n_models
+    r.fit(emb, a, b, rng.choice([0.0, 0.5, 1.0], n),
+          query_id=np.arange(n))
+    q = rng.normal(size=(13, 8)).astype(np.float32)
+    q[:5] = emb[[0, 3, 7, 500, 1100]]
+    return r, q, rng.uniform(0.5, 6.0, 13).astype(np.float32)
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas_interpret"])
+def test_two_stage_route_bit_identical_to_one_stage(backend):
+    """At a capacity that takes the two-stage top-k, route_batch_choices
+    and the dispatcher return the rows and choices of the same route
+    traced with lax.top_k, bit for bit, ties included."""
+    r, q, budgets = _wide_router(backend)
+    assert two_stage_topk(r.state.capacity, r.cfg.n_neighbors)
+    got = route_batch_choices(r.state, q, budgets, r.costs, **r._kw())
+    got_c, got_i = RouteDispatcher.for_router(r).route_result(
+        r.state, q, budgets)
+    with _traced_with_one_stage_topk():
+        want = route_batch_choices(r.state, q, budgets, r.costs, **r._kw())
+    want_i, want_c = np.asarray(want.topk_idx), np.asarray(want.choices)
+    np.testing.assert_array_equal(np.asarray(got.topk_idx), want_i)
+    np.testing.assert_array_equal(np.asarray(got.choices), want_c)
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_array_equal(got_c, want_c)
+
+
+@pytest.mark.parametrize("capacity,mode,two_stage", [
+    (64, "combined", False), (WIDE_CAPACITY, "combined", True),
+    (WIDE_CAPACITY, "global", False)])
+def test_two_stage_counter_counts_its_dispatches(capacity, mode, two_stage):
+    """dispatch_two_stage_topk_total counts every dispatch whose
+    executable takes the two-stage top-k, and none other."""
+    r, rng = _router(seed=12, capacity=capacity, mode=mode)
+    d = RouteDispatcher.for_router(r, max_bucket=16)
+    for nq in (3, 9, 30):                  # 30 rows: two dispatches
+        d.route(r.state, rng.normal(size=(nq, 8)).astype(np.float32), 3.0)
+    d.route_result(r.state, rng.normal(size=(5, 8)).astype(np.float32), 3.0)
+    reg = d.obs.registry
+    calls = reg.counter("dispatch_calls_total").value
+    assert calls == 5
+    assert reg.counter("dispatch_two_stage_topk_total").value == \
+        (calls if two_stage else 0)
 
 
 # ---------------------------------------------------------------------------

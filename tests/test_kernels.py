@@ -9,7 +9,8 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.elo_scan import elo_scan_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.similarity_topk import similarity_pallas
+from repro.kernels.similarity_topk import (TOPK_CHUNK, panel_topk,
+                                           similarity_pallas, two_stage_topk)
 from repro.kernels import ops
 
 
@@ -45,6 +46,62 @@ def test_similarity_topk_matches_bruteforce():
     for i in range(8):
         want = set(np.argsort(-s_ref[i])[:10].tolist())
         assert set(np.asarray(idx[i]).tolist()) == want
+
+
+# ---------------------------------------------------------------------------
+# two-stage top-k over the score panel
+# ---------------------------------------------------------------------------
+
+B = TOPK_CHUNK
+WIDE = 96 * B      # 12,288 rows: two stages at n = 20 (needs 80 chunks)
+
+# id: (q, c, n, scores, size, offset, two_stage)
+PANEL_CASES = {
+    "gaussian_all_live": (16, WIDE, 20, "gauss", WIDE, 0, True),
+    "integer_ties_all_live": (16, WIDE, 20, "ints", WIDE, 0, True),
+    "integer_ties_part_live": (16, WIDE, 20, "ints", 7_001, 0, True),
+    "peaked_ties_all_live": (16, WIDE, 20, "peaked", WIDE, 0, True),
+    "peaked_ties_part_live": (16, WIDE, 20, "peaked", 9_000, 0, True),
+    "size_0": (8, WIDE, 20, "gauss", 0, 0, True),
+    "size_at_chunk_boundary": (8, WIDE, 20, "ints", 40 * B, 0, True),
+    "size_inside_chunk": (8, WIDE, 20, "gauss", 40 * B + 77, 0, True),
+    "fewer_than_n_live": (8, WIDE, 20, "ints", 13, 0, True),
+    "one_live_chunk_ties": (8, WIDE, 20, "ints", B, 0, True),
+    "offset_shard_live": (8, WIDE, 20, "ints", 3 * WIDE - 500, 2 * WIDE, True),
+    "offset_shard_dead": (8, WIDE, 20, "gauss", 2 * WIDE - 1, 2 * WIDE, True),
+    "offset_size_inside": (8, WIDE, 20, "gauss", WIDE + 4_321, WIDE, True),
+    "q_not_multiple_of_8": (13, WIDE, 20, "ints", 9_999, 0, True),
+    "q_1": (1, WIDE, 20, "gauss", WIDE, 0, True),
+    "at_threshold": (8, 80 * B, 20, "ints", 80 * B - 3, 0, True),
+    "below_threshold": (8, 79 * B, 20, "gauss", 79 * B - 3, 0, False),
+    "small_n_narrow_panel": (8, 4 * B, 1, "ints", 4 * B - 1, 0, True),
+    "c_not_multiple_of_chunk": (8, WIDE + 5, 20, "gauss", WIDE, 0, False),
+}
+
+
+@pytest.mark.parametrize("case", list(PANEL_CASES))
+def test_panel_topk_bitwise_equals_lax_top_k(case):
+    """panel_topk against lax.top_k over the live-masked panel: scores
+    (as bits) and row ids equal, -inf rows and ties included; each case
+    takes the path its shape says."""
+    q, c, n, kind, size, offset, two_stage = PANEL_CASES[case]
+    assert two_stage_topk(c, n) is two_stage
+    rng = np.random.default_rng(sum(map(ord, case)))
+    if kind == "gauss":
+        s = rng.normal(size=(q, c)).astype(np.float32)
+    else:   # few distinct values: every top n is decided by ties
+        s = rng.integers(-3, 4, (q, c)).astype(np.float32)
+    if kind == "peaked":    # a high row late in the live range ranks its
+        # chunk first, ahead of lower chunks tied at the next value
+        live_c = size - offset
+        s[np.arange(q), rng.integers(live_c // 2, live_c, q)] = 10.0
+    s = jnp.asarray(s)
+    live = (jnp.arange(c) + offset) < size
+    want_s, want_i = jax.lax.top_k(jnp.where(live[None, :], s, -jnp.inf), n)
+    got_s, got_i = jax.jit(panel_topk, static_argnums=1)(s, n, size, offset)
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(got_s).view(np.uint32),
+                                  np.asarray(want_s).view(np.uint32))
 
 
 # ---------------------------------------------------------------------------

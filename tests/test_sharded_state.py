@@ -105,3 +105,18 @@ def test_sharded_programs_carry_the_stage_scopes():
                                      "eagle.gather", "eagle.merge",
                                      "eagle.replay"]), bk
     assert scopes["commit"] is True
+
+
+def test_two_stage_topk_shards_bit_identical_and_counted():
+    """A DB wide enough that every shard's top-k takes two stages, with
+    ties straddling the shard boundaries: the dispatcher's route equals
+    one device's bit for bit on every mesh and backend, and counts each
+    of its dispatches as two-stage; at the small capacity it counts
+    none."""
+    wide = report()["wide"]
+    assert wide["two_stage_per_shard"] == {m: True for m in MESHES}
+    for mesh in MESHES:
+        for bk in ("reference", "pallas_interpret"):
+            assert wide[f"{mesh}/{bk}"] == {
+                "equal": True, "calls": 3, "two_stage": 3}, (mesh, bk)
+    assert wide["narrow"] == {"calls": 3, "two_stage": 0}

@@ -12,6 +12,7 @@ is off around these compiles: an entry written for a described chip
 cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ from repro.core import state as STATE
 from repro.core.state import (RouterState, route_batch_choices,
                               route_batch_choices_sharded, state_shardings)
 from repro.kernels.elo_scan import elo_scan_pallas, elo_scan_select_pallas
-from repro.kernels.similarity_topk import similarity_pallas
+from repro.kernels.similarity_topk import similarity_pallas, two_stage_topk
 
 D, M, N, R, Q = 1536, 10, 20, 8, 256   # paper router, fleet of 10
 KW = dict(p_global=0.5, n_neighbors=N, k=32.0, backend="pallas",
@@ -113,6 +114,47 @@ def test_route_batch_choices_pallas_compiles_at_paper_width(one_chip):
     assert _custom_calls(c) == 2
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+
+
+def _entry_ops(compiled):
+    """(element count, opcode, line) of each array-valued op of the
+    entry computation: the buffers the program materialises."""
+    txt = compiled.as_text()
+    out = []
+    for line in txt[txt.index("\nENTRY"):].splitlines()[1:]:
+        m = _OP.match(line)
+        if m:
+            dims = [int(x) for x in m.group(1).split(",") if x]
+            out.append((int(np.prod(dims)), m.group(2), line))
+    return out
+
+
+def test_route_topk_reads_the_paper_panel_once_in_place(one_chip):
+    """At Q = 256 over 2^20 rows the route's top-k takes two stages
+    (two TopK calls: the chunk maxima's and the candidates'). Nothing
+    but the similarity kernel materialises an array of the panel's
+    size: no masked panel, no relayout copy. Temporaries are the
+    panel, the chunk maxima (a lane reduce writes 8 values of each
+    (8, 128) tile row padded to a lane row: one eighth of the panel)
+    and under 64 MiB besides; the one-stage top-k's were the panel and
+    1.3 MB (1,075,001,856 B)."""
+    cap = 1 << 20
+    assert two_stage_topk(cap, N)
+    c = route_batch_choices.lower(
+        _state(cap, one_chip), _sds((Q, D), jnp.float32, one_chip),
+        _sds((Q,), jnp.float32, one_chip),
+        _sds((M,), jnp.float32, one_chip), **KW).compile()
+    assert c.as_text().count('custom_call_target="TopK"') == 2
+    big = [line for n, op, line in _entry_ops(c)
+           if n >= Q * cap and op not in ("bitcast", "parameter")]
+    assert len(big) == 1 and "eagle_similarity" in big[0] \
+        and "f32[256,1048576]" in big[0], big
+    panel_bytes = Q * cap * 4
+    assert c.memory_analysis().temp_size_in_bytes < \
+        panel_bytes + panel_bytes // 8 + (64 << 20)
 
 
 def test_sharded_route_and_commit_compile_on_four_described_chips(topo):
