@@ -1,15 +1,20 @@
 """The router's feedback history and its query traffic, made from the seed.
 
-The history is made on the device in one jitted call: random unit
-directions (as `data.routerbench.bulk_history` draws them) with 1 to R-1
-pairwise records per prompt, so one slot stays free. Its host copy is
-what the program's VectorDB is filled from, and the benchmark keeps it:
-the reference rebuilds the DB from it and from the feedback the run
-folded, never from the program's buffers.
+The history is random unit directions (as `data.routerbench.bulk_history`
+draws them) with 1 to R-1 pairwise records per prompt, so one slot stays
+free. An unsharded DB's history is made on the device in one jitted
+call. A DB sharded over S devices may be larger than any one of them
+holds, so its history is made one shard's block of rows at a time, each
+on its shard's device from a key of its own, and copied to the host as
+soon as it is made. The host copy is what the program's VectorDB is
+filled from, and the benchmark keeps it: the reference rebuilds the DB
+from it and from the feedback the run folded, never from the program's
+buffers.
 """
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import List
 
@@ -46,7 +51,7 @@ class History:
     """Host copy of the generated history. Rows [0, fit_rows) seed the
     global ratings through EagleRouter.fit with their first record
     only; rows [fit_rows, n) are bulk-loaded with all their records."""
-    raw: np.ndarray        # (n, D) f32, not normalized
+    raw: np.ndarray        # (n, D) f32, not normalized (Rows if sharded)
     a: np.ndarray          # (n, R) int32
     b: np.ndarray
     o: np.ndarray          # (n, R) f32 in {0, 0.5, 1}
@@ -75,29 +80,105 @@ class History:
 
 def build_history(seed: int, *, rows: int, dim: int, n_models: int,
                   records: int, fit_rows: int, n_queries: int,
-                  noise: float):
-    """Make the history and a pool of n_queries queries on the device,
-    copy both to the host. Returns (History, queries (n_queries, D) f32)."""
+                  noise: float, shards: int = 1, capacity: int = 0):
+    """Make the history and a pool of n_queries queries, copy both to
+    the host. With shards > 1 the history is made in blocks of the
+    shard's capacity // shards rows (history_blocks). Returns (History,
+    queries (n_queries, D) f32)."""
     ks = seed32(seed, 1)
-    dev = make_history(jax.random.key(ks), n=rows, d=dim, m=n_models,
-                       r=records)
     src = np.random.default_rng([seed % (1 << 64), 2]).integers(
         0, rows, n_queries)
-    q = make_queries(jax.random.key(seed32(seed, 3)), dev[0],
-                     jnp.asarray(src, jnp.int32), noise=noise)
-    host = [np.asarray(x) for x in dev]
+    qkey = jax.random.key(seed32(seed, 3))
+    if shards == 1:
+        dev = make_history(jax.random.key(ks), n=rows, d=dim, m=n_models,
+                           r=records)
+        q = make_queries(qkey, dev[0], jnp.asarray(src, jnp.int32),
+                         noise=noise)
+        host = [np.asarray(x) for x in dev]
+        del dev
+    else:
+        host = history_blocks(seed, rows=rows, dim=dim, n_models=n_models,
+                              records=records,
+                              block_rows=capacity // shards,
+                              devices=jax.devices()[:shards])
+        q = make_queries(qkey, jnp.asarray(host[0][src]),
+                         jnp.arange(n_queries, dtype=jnp.int32), noise=noise)
     queries = np.asarray(q)
-    del dev, q
+    del q
     hist = History(*host, fit_rows=fit_rows, key_seed=ks)
     return hist, queries
 
 
-def regenerate_raw(hist: History, dim: int, n_models: int):
-    """The history's embeddings again on the device (same key, same
-    program: the same bits), for the reference's search."""
-    dev = make_history(jax.random.key(hist.key_seed), n=hist.n, d=dim,
-                       m=n_models, r=hist.a.shape[1])
-    return dev[0]
+def history_blocks(seed: int, *, rows: int, dim: int, n_models: int,
+                   records: int, block_rows: int, devices):
+    """The history in blocks of block_rows rows (the last one shorter):
+    block b is made from the key seed32(seed, 1, b) on
+    devices[b % len(devices)] and copied to the host, one host thread a
+    device, side by side (4 x 2^20 rows on a 4-chip v5e host: 33-47 s,
+    against 51-54 s one block after another). Its embeddings stay on the
+    host as they arrive (a Rows of the blocks: no second copy of the
+    whole), and the device's copy is freed, so no device holds more than
+    one block. Returns host (raw, a, b, o, n_rec)."""
+    recs = [np.empty((rows, records), np.int32),
+            np.empty((rows, records), np.int32),
+            np.empty((rows, records), np.float32),
+            np.empty((rows,), np.int32)]
+    starts = list(range(0, rows, block_rows))
+
+    def make(blk: int):
+        lo = starts[blk]
+        hi = min(lo + block_rows, rows)
+        key = jax.device_put(jax.random.key(seed32(seed, 1, blk)),
+                             devices[blk % len(devices)])
+        emb, *rest = make_history(key, n=hi - lo, d=dim, m=n_models,
+                                  r=records)
+        for dst, x in zip(recs, rest):
+            dst[lo:hi] = np.asarray(x)
+        return np.asarray(emb)
+
+    with ThreadPoolExecutor(len(devices)) as ex:
+        raw = Rows(list(ex.map(make, range(len(starts)))))
+    return [raw, *recs]
+
+
+class Rows:
+    """An (n, D) array held as host blocks in row order. Row slices
+    inside one block are views of it; gathers and slices across blocks
+    copy only the rows they take."""
+
+    def __init__(self, blocks):
+        self.blocks = blocks
+        self.starts = np.cumsum([0] + [len(b) for b in blocks])
+        self.shape = (int(self.starts[-1]),) + blocks[0].shape[1:]
+        self.dtype = blocks[0].dtype
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def __getitem__(self, idx):
+        if isinstance(idx, slice):
+            lo, hi, step = idx.indices(len(self))
+            if step != 1:
+                raise IndexError("Rows takes row slices of step 1")
+            parts = [b[max(lo - s, 0):hi - s]
+                     for b, s in zip(self.blocks, self.starts)
+                     if s < hi and lo < s + len(b)]
+            if len(parts) == 1:
+                return parts[0]
+            if not parts:
+                return np.empty((0,) + self.shape[1:], self.dtype)
+            return np.concatenate(parts)
+        idx = np.asarray(idx)
+        blk = np.searchsorted(self.starts, idx, side="right") - 1
+        out = np.empty(idx.shape + self.shape[1:], self.dtype)
+        for k in np.unique(blk):
+            at = blk == k
+            out[at] = self.blocks[k][idx[at] - self.starts[k]]
+        return out
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.concatenate(self.blocks)
+        return out if dtype is None else out.astype(dtype)
 
 
 @dataclasses.dataclass

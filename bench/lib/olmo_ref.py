@@ -119,22 +119,22 @@ def forward(params, tokens, *, theta: float = 10000.0, fp8: bool = False):
     return mm("bsd,vd->bsv", q8(_ln(x)), q8(emb))
 
 
-def served_token_gaps(params, prompts, served, *, theta=10000.0,
-                      fp8_tokens=False):
+def served_token_gaps(cfg: dict, params, prompts, served, control=False):
     """For each sequence, teacher-forced on prompt + served tokens: how
     far each served token's reference logit lies below the reference's
-    best at that position. With fp8_tokens=True the tokens judged are
-    the ones the fp8 control puts first at each position instead.
+    best at that position. With control=True the tokens judged are the
+    ones the fp8 control puts first at each position instead.
     prompts (B, P) int, served (B, T) int (-1 pads). Returns the widest
     gap per sequence (B,)."""
     import numpy as np
+    theta = cfg["rope_theta"]
     b, p = prompts.shape
     t = served.shape[1]
     seq = np.concatenate([prompts, np.maximum(served, 0)], 1)[:, :p + t - 1]
     logits = forward(params, jnp.asarray(seq, jnp.int32), theta=theta)
     # position p-1+i predicts served token i
     ref = np.asarray(logits[:, p - 1:p - 1 + t], np.float64)
-    if fp8_tokens:
+    if control:
         ctl = forward(params, jnp.asarray(seq, jnp.int32), theta=theta,
                       fp8=True)
         tok = np.asarray(jnp.argmax(ctl[:, p - 1:p - 1 + t], -1))

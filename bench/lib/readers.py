@@ -14,6 +14,9 @@ import numpy as np
 _HLO = re.compile(r"^%\S+ = (.+?) ([a-zA-Z][\w\-]*)\(")
 ROUTE_MODULE = "jit_route_batch_choices"       # also ..._sharded
 SCATTER_MODULE = "jit__scatter_rows"
+#: the sharded commit's owner-scatter (state._sharded_scatter): a jit of
+#: shard_map over its inner `body`
+SHARD_SCATTER_MODULE = "jit_body"
 
 
 def router_shapes(ctx):
@@ -67,6 +70,25 @@ def replay_kernel(ctx):
         result, _, _ = _parts(name)
         return (module.startswith(ROUTE_MODULE) and "tpu_custom_call" in name
                 and not result.startswith(panel))
+    return match
+
+
+def merge_ops(ctx):
+    """Ops of the sharded route's cross-shard merge: its all-gathers, in
+    whatever form XLA emits them, and the top-k over the (Q, S*N) pool
+    of gathered candidates (a TopK, or the sort XLA makes of it)."""
+    s = router_shapes(ctx)
+    pool = f"(f32[{ctx['counters']['window_rows']},{s['shards'] * s['n']}]"
+
+    def match(name, module):
+        if not module.startswith(ROUTE_MODULE):
+            return False
+        result, op, rest = _parts(name)
+        if op in ("all-gather", "all-gather-start", "all-gather-done"):
+            return True
+        return result.startswith(pool) and (
+            op in ("sort", "topk")
+            or (op == "custom-call" and '"TopK"' in rest))
     return match
 
 

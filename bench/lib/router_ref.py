@@ -119,9 +119,63 @@ def _search_block(panels, q, sizes, *, n: int, control: bool):
 
 
 def device_search(panels, queries, sizes, n: int, *, control: bool = False,
-                  block: int = 256):
-    """Blocked top-n search on the device. Returns host (scores, rows)."""
-    out_s, out_i = [], []
+                  block: int = 256, block_rows=None, devices=None):
+    """Top-n search on the device over the rows of `panels` (host arrays
+    (rows_i, D), one panel in row order), in blocks of at most
+    `block_rows` rows (default: all of them, one block). Block k is put
+    on devices[k % len(devices)] (default: JAX's default device) and
+    searched `block` queries at a time; its top-n keep their global row
+    ids. A round puts one block on each device and reads its results
+    back before the next, so no device holds two. Blocks' candidates
+    are merged on the host, ties to the lower row: the order lax.top_k
+    gives over the concatenation, so the result is that of one block.
+    Returns host (scores, rows)."""
+    total = sum(len(p) for p in panels)
+    block_rows = block_rows or total
+    devices = list(devices) if devices else [None]
+    sizes = np.asarray(sizes)
+    starts = list(range(0, total, block_rows))
+    found = []
+    for r0 in range(0, len(starts), len(devices)):
+        pending = []
+        for lo, dev in zip(starts[r0:r0 + len(devices)], devices):
+            parts = tuple(jax.device_put(p, dev) for p in
+                          _row_slices(panels, lo, lo + block_rows))
+            pending.append((lo, _search_queries(
+                parts, queries, sizes - lo, n, control, block, dev)))
+            del parts
+        for lo, runs in pending:
+            s = np.concatenate([np.asarray(s)[:k] for s, _, k in runs])
+            i = np.concatenate([np.asarray(i)[:k] for _, i, k in runs])
+            found.append((s, i + lo))
+    if len(found) == 1:
+        return found[0]
+    s = np.concatenate([f[0] for f in found], axis=1)
+    i = np.concatenate([f[1] for f in found], axis=1)
+    # a stable sort keeps equal scores in block order, and each block's
+    # equal scores in row order
+    order = np.argsort(-s, axis=1, kind="stable")[:, :n]
+    return (np.take_along_axis(s, order, axis=1),
+            np.take_along_axis(i, order, axis=1))
+
+
+def _row_slices(panels, lo: int, hi: int):
+    """Views of rows [lo, hi) of the panels taken as one, panel by
+    panel."""
+    out, at = [], 0
+    for p in panels:
+        a, b = max(lo - at, 0), min(hi - at, len(p))
+        if a < b:
+            out.append(p[a:b])
+        at += len(p)
+    return out
+
+
+def _search_queries(parts, queries, sizes, n, control, block, dev):
+    """_search_block over every `block` queries, enqueued on `dev`.
+    Returns per query block (device scores, device local rows, number
+    of queries kept)."""
+    runs = []
     nq = len(queries)
     for lo in range(0, nq, block):
         q = queries[lo:lo + block]
@@ -130,12 +184,12 @@ def device_search(panels, queries, sizes, n: int, *, control: bool = False,
         if pad:
             q = np.concatenate([q, np.repeat(q[:1], pad, 0)])
             sz = np.concatenate([sz, np.repeat(sz[:1], pad)])
-        s, i = _search_block(tuple(panels), jnp.asarray(q, jnp.float32),
-                             jnp.asarray(sz, jnp.int32), n=n,
-                             control=control)
-        out_s.append(np.asarray(s)[:block - pad])
-        out_i.append(np.asarray(i)[:block - pad])
-    return np.concatenate(out_s), np.concatenate(out_i)
+        s, i = _search_block(parts,
+                             jax.device_put(np.asarray(q, np.float32), dev),
+                             jax.device_put(np.asarray(sz, np.int32), dev),
+                             n=n, control=control)
+        runs.append((s, i, block - pad))
+    return runs
 
 
 def exact_cosines(row_emb_fn, queries, rows):

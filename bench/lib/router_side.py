@@ -11,11 +11,12 @@ import gc
 import time
 from typing import List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bench.lib import router_ref as REF
-from bench.lib.history import FeedbackLog, build_history, regenerate_raw
+from bench.lib.history import FeedbackLog, build_history
 from bench.lib.tracing import phase
 
 #: rows the DB-state check reads back besides every row the window added
@@ -48,13 +49,15 @@ class RouterSide:
         self.n_models = len(fleet["names"])
         self.costs = np.asarray(fleet["costs"], np.float32)
         self.capacity = db["capacity"]
+        self.shards = db.get("shards", 1)
         self.records = db["records_per_prompt"]
         n_hist = self.capacity - db["headroom"]
         with phase(log, "history made and copied to the host"):
             self.hist, self.queries = build_history(
                 seed, rows=n_hist, dim=self.dim, n_models=self.n_models,
                 records=self.records, fit_rows=db["fit_prompts"],
-                n_queries=n_queries, noise=noise)
+                n_queries=n_queries, noise=noise, shards=self.shards,
+                capacity=self.capacity)
         with phase(log, "router fitted and DB filled"):
             self._fill(cfg)
         self.fb = FeedbackLog()
@@ -94,11 +97,10 @@ class RouterSide:
         router.feedback = feedback
 
     def mesh(self):
-        shards = self.cfg["db"].get("shards", 1)
-        if shards == 1:
+        if self.shards == 1:
             return None
         from repro.launch.mesh import make_db_mesh
-        return make_db_mesh(shards)
+        return make_db_mesh(self.shards)
 
     # -- the reference's view of the DB ------------------------------------------
     def _row_emb(self, fb_emb):
@@ -205,7 +207,6 @@ class RouterSide:
         q = np.concatenate([s.queries for s in samples])
         sizes = np.concatenate([np.full(len(s.queries), s.size)
                                 for s in samples])
-        raw = regenerate_raw(self.hist, self.dim, m)
         # the feedback rows padded to the headroom: one search program
         # per configuration, whatever number of rows the run added
         head = self.capacity - self.hist.n
@@ -213,12 +214,16 @@ class RouterSide:
             raise ValueError("the run added more rows than the headroom")
         fb_panel = np.zeros((head, self.dim), np.float32)
         fb_panel[:len(fb[0])] = fb[0]
-        panels = [raw, jnp.asarray(fb_panel)]
-        _, cand = REF.device_search(panels, q, sizes, n_nb + REF.CAND_EXTRA)
+        # searched in blocks of one shard's rows, over the cell's devices
+        search = dict(block_rows=self.capacity // self.shards,
+                      devices=jax.devices()[:self.shards])
+        panels = [self.hist.raw, fb_panel]
+        _, cand = REF.device_search(panels, q, sizes, n_nb + REF.CAND_EXTRA,
+                                    **search)
         if control:
             _, got_top = REF.device_search(panels, q, sizes, n_nb,
-                                           control=True)
-        del raw, panels
+                                           control=True, **search)
+        del panels, fb_panel
         ref_rows, ref_cos = REF.exact_topk(row_emb, q, cand, n_nb)
         have_topk = all(s.topk is not None for s in samples)
         if not control and have_topk:

@@ -8,12 +8,12 @@ unfinished counts as missing.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import time
 import zlib
 
 import numpy as np
 
-from bench.lib import olmo_ref
 from bench.lib import traffic as TR
 from bench.lib.router_side import RouteSample, RouterSide
 from bench.lib.tracing import phase, span
@@ -27,6 +27,14 @@ def _oracle(emb, mi) -> float:
     return float(np.random.default_rng(
         [zlib.crc32(np.asarray(emb, np.float32).tobytes()), int(mi)]
     ).random())
+
+
+def reference_module(reference: dict):
+    """The plain reference of the checked member: the module under
+    bench/lib/ that the configuration's `fleet.reference.module` names.
+    It exposes init_params(reference, seed) and served_token_gaps(
+    reference, params, prompts, served, control)."""
+    return importlib.import_module(f"bench.lib.{reference['module']}")
 
 
 class ServeCell:
@@ -70,10 +78,11 @@ class ServeCell:
             fleet[name] = FleetModel(
                 get_config(name) if full else get_reduced_config(name),
                 seed=ms, max_len=f["max_len"])
-        # the checked member serves the benchmark's weights, which the
+        # the checked member serves the benchmark's weights, which its
         # reference can make again (bench/lib/olmo_ref.py says why)
         checked = f["checked"]
-        fleet[checked].params = olmo_ref.init_params(
+        self.ref = reference_module(f["reference"])
+        fleet[checked].params = self.ref.init_params(
             f["reference"], self.member_seeds[checked])
         self.log(f"phase fleet initialised: "
                  f"{time.perf_counter() - t_fleet:.2f} s")
@@ -143,7 +152,6 @@ class ServeCell:
     def run_window(self, seconds: float, tracer=None):
         q = self.queue
         self.obs.enabled = self.obs.tracer.enabled = tracer is not None
-        self.obs.tracer.xprof = tracer is not None
         self._sampling = True
         t0 = time.perf_counter_ns()
         due_ns = t0 + (self.due_s * 1e9).astype(np.int64)
@@ -205,14 +213,20 @@ class ServeCell:
     def failed(self) -> int:
         return int((~self._ok()).sum())
 
+    def _e2e_ms(self, ok):
+        """Each request's time from its due time to its last token on
+        the host; one never answered in full counts until the give-up."""
+        return np.where(ok, self.end - self.due_ns,
+                        self.t_give_up - self.due_ns) / 1e6
+
     def end_to_end(self):
         ok = self._ok()
-        e2e = np.where(ok, self.end - self.due_ns,
-                       self.t_give_up - self.due_ns) / 1e6
+        e2e = self._e2e_ms(ok)
         t_last = self.end[ok].max() if ok.any() else self.t_give_up
         toks = float(self.max_new[ok].sum())
-        # the manifest reports the median; the mean, the tail and the
-        # token rate (below the knee, the offered load) are printed
+        # the manifest reports the token rate (completed tokens over the
+        # window and its drain); the median is a per-layer reading
+        # (bench/metrics/e2e_p50_ms.serve.py), the mean and tail printed
         return {"e2e_p50_ms": (float(np.percentile(e2e, 50)), "ms"),
                 "e2e_mean_ms": (float(e2e.mean()), "ms"),
                 "e2e_p95_ms": (float(np.percentile(e2e, 95)), "ms"),
@@ -237,6 +251,7 @@ class ServeCell:
                 "flush_sizes": [len(smp.choices) for smp in self.samples],
                 "serve_s": self.serve_ns / 1e9,
                 "served": self.served, "max_new": self.max_new,
+                "e2e_ms": self._e2e_ms(ok),
                 "t0_ns": self.t0,
                 "t_last_ns": int(self.end[ok].max()) if ok.any() else
                 self.t_give_up}
@@ -281,15 +296,14 @@ class ServeCell:
             nums["logit_gap"] = float("inf")
             return nums
         t_ref = time.perf_counter()
-        params = olmo_ref.init_params(self.full_cfg, self.full_seed)
+        params = self.ref.init_params(self.full_cfg, self.full_seed)
         t = max(len(s) for _, s in self.gen_sample)
         prompts = np.stack([p for p, _ in self.gen_sample])
         served = np.full((len(self.gen_sample), t), -1, np.int64)
         for j, (_, s) in enumerate(self.gen_sample):
             served[j, :len(s)] = s
-        gaps = olmo_ref.served_token_gaps(
-            params, prompts, served, theta=self.full_cfg["rope_theta"],
-            fp8_tokens=control)
+        gaps = self.ref.served_token_gaps(self.full_cfg, params, prompts,
+                                          served, control)
         nums["logit_gap"] = float(gaps.max())
         self.log(f"phase reference forward ({len(self.gen_sample)} "
                  f"requests): {time.perf_counter() - t_ref:.2f} s")

@@ -23,12 +23,29 @@ _DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 _HOST_SPAN = re.compile(r"^(bench|serve|admission|dispatch|router|state)\.")
 
 
+def host_memory() -> str:
+    """This process's peak resident set and the memory the machine has
+    left (Linux)."""
+    import resource
+    peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+    avail = "unknown"
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    avail = f"{int(line.split()[1]) * 1024 / 1e9:.1f} GB"
+    except OSError:
+        pass
+    return f"host peak RSS {peak / 1e9:.1f} GB, available {avail}"
+
+
 @contextmanager
 def phase(log, name: str):
-    """Log how long a phase of set-up or checking took (stderr)."""
+    """Log how long a phase of set-up or checking took, and the host's
+    memory after it (stderr)."""
     t0 = time.perf_counter()
     yield
-    log(f"phase {name}: {time.perf_counter() - t0:.2f} s")
+    log(f"phase {name}: {time.perf_counter() - t0:.2f} s; {host_memory()}")
 
 
 def span(name: str):

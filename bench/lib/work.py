@@ -47,6 +47,14 @@ def route_step(q: int, c: int, d: int, n: int, records: int, m: int):
     return f1 + f2, b1 + b2
 
 
+def merge_exchange(q: int, n: int, records: int, shards: int) -> float:
+    """Bytes the cross-shard merge gathers onto each device a dispatch:
+    every shard's n candidates per query, each a score, a row id and
+    its records (model a, model b, outcome, valid)."""
+    per_candidate = F32 + I32 + records * (I32 + I32 + F32 + BOOL)
+    return float(shards * q * n * per_candidate)
+
+
 # ---------------------------------------------------------------------------
 # dense decoder (OLMo-style: MHA, gated MLP, tied embeddings)
 # ---------------------------------------------------------------------------

@@ -1,8 +1,9 @@
-"""The routing step's share of the chip's peak: the required FLOPs of
-every route dispatch the trace holds (retrieval and replay,
-bench.lib.work.route_step, live rows only) over the traced stretch's
-seconds times the peak FLOP/s. Dispatches are counted as runs of the
-similarity kernel."""
+"""The routing step's share of the peak of the chips it runs on: the
+required FLOPs of every route dispatch the trace holds (retrieval over
+every shard's rows and one replay, bench.lib.work.route_step, live rows
+only) over the traced stretch's seconds times the peak FLOP/s of one
+chip a shard. Dispatches are counted as runs of the similarity kernel,
+per device."""
 from bench.lib import readers as R
 from bench.lib import work
 
@@ -15,5 +16,5 @@ def read(ctx):
     s = R.router_shapes(ctx)
     flops, _ = work.route_step(c["window_rows"], s["c"], s["d"], s["n"],
                                s["r"], s["m"])
-    return R.percent(flops * calls / (tr.window_s
-                                      * ctx["peaks"]["flops_per_s"]))
+    peak = s["shards"] * ctx["peaks"]["flops_per_s"]
+    return R.percent(flops * calls / (tr.window_s * peak))

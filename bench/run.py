@@ -153,6 +153,7 @@ def main(argv=None) -> int:
         from repro.launch.compile_cache import enable_compile_cache
         log(f"compile cache: {enable_compile_cache()}")
     from bench.lib.peaks import UnknownDevice, peaks_for
+    from bench.lib.tracing import host_memory
     try:
         peaks = peaks_for(devs[0].device_kind)
     except UnknownDevice:
@@ -171,7 +172,7 @@ def main(argv=None) -> int:
     runner.setup(args.seconds)
     setup_s = time.perf_counter() - T_START
     log(f"setup_s {setup_s:.3f} ({cc_setup.delta()} compiles, "
-        f"{len(hits)} of them from the persistent cache)")
+        f"{len(hits)} of them from the persistent cache); {host_memory()}")
 
     tracer = None
     tmp = None
@@ -226,7 +227,8 @@ def main(argv=None) -> int:
               for k in limits}
     checks["failed"] = {"value": int(failed), "limit": 0}
     correct = failed == 0 and all(nums[k] <= limits[k] for k in limits)
-    log(f"reference and checks took {time.perf_counter() - t_ref:.2f} s")
+    log(f"reference and checks took {time.perf_counter() - t_ref:.2f} s; "
+        f"{host_memory()}")
     rehearsal_trace = None
     if args.rehearse:
         rehearsal_trace = {k: dev.pop(k) for k in ("busy_s", "window_s")

@@ -74,6 +74,19 @@ def test_traced_rehearsal_reduces_its_trace():
     assert any(n.startswith("bench.") for n in names), names
 
 
+def test_traced_sharded_rehearsal_reduces_its_trace():
+    """On 4 host devices the sharded cell's history and reference search
+    take the block path, and its traced run reduces all 4 devices."""
+    r = _rehearse("tiny.route4", "--trace", "1", env=ENV4)
+    assert r["correct"] is True, r["checks"]
+    assert r["device"]["count"] == 4
+    assert r["rehearsal_trace"]["busy_s"] > 0
+    assert r["breakdown"]["device_ops"]
+    for name in ("dispatch_idle_ms.route", "commit_idle_ms.route",
+                 "commit_device_ms.shard"):
+        assert name in r["rehearsal_readings"], r["rehearsal_readings"]
+
+
 @pytest.mark.parametrize("workload,seconds", [("tiny.route", 1),
                                               ("tiny.serve", 3)])
 def test_control_fails_where_the_program_passes(workload, seconds):

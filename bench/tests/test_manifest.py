@@ -53,6 +53,35 @@ def test_cells_find_their_files():
         1, len(M["workloads"]) // 2)
 
 
+def test_sharded_cell():
+    """The 4-chip cell: its configuration splits capacity evenly over
+    its shards, one shard a chip, and at most half the cells (or one)
+    take 4 chips."""
+    cells = {w["name"]: w for w in M["workloads"]}
+    configs = {c["name"]: c for c in M["configs"]}
+    for w in M["workloads"]:
+        conf = configs[w["config"]]["file"]
+        db = json.loads((ROOT / conf).read_text())["db"]
+        assert db["capacity"] % db["shards"] == 0
+        assert db["shards"] in (1, w["chips"])
+        assert db["capacity"] - db["headroom"] >= db["fit_prompts"]
+    assert cells["route.paper4m.4chip"]["chips"] == 4
+    assert cells["route.paper4m.4chip"]["config"] == "eagle-paper-4m-sharded"
+    four = sum(w["chips"] == 4 for w in M["workloads"])
+    assert four <= max(1, len(M["workloads"]) // 2)
+
+
+def test_metric_cells_exist():
+    cells = {w["name"] for w in M["workloads"]}
+    for e in M["end_to_end"] + M["per_layer"]:
+        assert set(e.get("workloads", cells)) <= cells, e["name"]
+    layers = {}
+    for p in M["per_layer"]:
+        assert 1 <= len(p["layer"]) <= 200
+        layers.setdefault(p["layer"].lower(), set()).add(p["layer"])
+    assert all(len(v) == 1 for v in layers.values()), layers
+
+
 def _reports(entry, cell):
     return "workloads" not in entry or cell in entry["workloads"]
 
