@@ -19,14 +19,25 @@ _MODULES: Dict[str, str] = {
     "deepseek-v3-671b": "deepseek_v3_671b",
 }
 
+#: the assigned architecture pool: the routing corpus's fleet
+#: (data/routerbench.py), the dry-run's and the trainer's choices
 ARCH_IDS: List[str] = list(_MODULES)
+
+# published members the benchmark serves besides the pool
+_MODULES.update({
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
+    "moonlight-16b-a3b-ep8": "moonlight_16b_a3b",
+})
+#: every registered configuration
+ALL_IDS: List[str] = list(_MODULES)
 
 
 def get_config(arch: str) -> ModelConfig:
     if arch not in _MODULES:
-        raise KeyError(f"unknown arch {arch!r}; known: {ARCH_IDS}")
+        raise KeyError(f"unknown arch {arch!r}; known: {ALL_IDS}")
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
-    return mod.CONFIG
+    return next(c for c in vars(mod).values()
+                if isinstance(c, ModelConfig) and c.name == arch)
 
 
 def get_reduced_config(arch: str, **overrides) -> ModelConfig:

@@ -4,7 +4,11 @@ top-8) + MTP.
 The assigned d_ff=2048 is the per-expert (routed/shared) hidden size; the
 first 3 layers are dense with the paper's 18432 hidden (Table 1 of
 arXiv:2412.19437). MLA dims follow the paper: q_lora 1536, kv_lora 512,
-qk_nope 128, qk_rope 64, v_head 128.
+qk_nope 128, qk_rope 64, v_head 128. The router is the published one
+(config.json: scoring_func sigmoid, topk_method noaux_tc, n_group 8,
+topk_group 4, norm_topk_prob true, routed_scaling_factor 2.5): sigmoid
+scores, experts chosen on score plus score-correction bias among the
+best 4 of 8 groups, weights the unbiased scores normalised and scaled.
 """
 from repro.models.config import ModelConfig
 
@@ -29,6 +33,10 @@ CONFIG = ModelConfig(
     experts_per_tok=8,
     n_shared_experts=1,
     first_k_dense=3,
+    router_score="sigmoid",
+    routed_scale=2.5,
+    n_group=8,
+    topk_group=4,
     aux_loss_coef=0.001,  # ds3 is aux-free-biased; keep a small seq-wise aux
     mtp_depth=1,
     tie_embeddings=False,

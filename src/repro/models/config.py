@@ -48,8 +48,22 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_d_ff: int = 0          # per-expert hidden size (0 -> d_ff)
     first_k_dense: int = 0     # deepseek: first k layers use dense FFN
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training only: serving is dropless
     aux_loss_coef: float = 0.01
+    # router: softmax scores (phi3.5), or sigmoid scores with a
+    # score-correction bias that only chooses experts (deepseek-v3's
+    # noaux_tc); the chosen scores are normalised, then scaled by
+    # `routed_scale`; with n_group > 1 only experts of the best
+    # `topk_group` groups compete
+    router_score: str = "softmax"
+    routed_scale: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
+    # expert parallelism: this device holds experts
+    # [expert_offset, expert_offset + experts_held) of n_experts
+    # (0 -> all); the router still scores all n_experts
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # -- SSM (mamba2) --------------------------------------------------------
     ssm_state: int = 0
@@ -76,6 +90,8 @@ class ModelConfig:
 
     # -- norms / embeddings ------------------------------------------------------
     norm: str = "rmsnorm"        # rmsnorm | layernorm | nonparam_ln
+    norm_eps: float = 1e-6
+    embed_scale: bool = True     # multiply token embeddings by sqrt(d_model)
     tie_embeddings: bool = True
     logit_softcap: float = 0.0
 
@@ -110,6 +126,11 @@ class ModelConfig:
     @property
     def expert_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights live on this device."""
+        return self.experts_held or self.n_experts
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer block kind for the decoder stack.
@@ -158,6 +179,11 @@ class ModelConfig:
             assert self.n_heads % max(self.n_kv_heads, 1) == 0 or self.attn_kind == "mla"
         if self.arch_type == "moe":
             assert self.n_experts > 0 and self.experts_per_tok > 0
+            assert self.router_score in ("softmax", "sigmoid")
+            assert 0 <= self.expert_offset
+            assert self.expert_offset + self.held_experts <= self.n_experts
+            assert self.n_experts % self.n_group == 0
+            assert 1 <= self.topk_group <= self.n_group
         if self.arch_type in ("ssm", "hybrid"):
             assert self.ssm_state > 0
             assert self.d_inner % self.ssm_head_dim == 0
@@ -211,7 +237,7 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> float:
         elif k == "moe":
             p += _attn_params(cfg)
             n_e = (cfg.experts_per_tok + cfg.n_shared_experts) if active_only \
-                else (cfg.n_experts + cfg.n_shared_experts)
+                else (cfg.held_experts + cfg.n_shared_experts)
             p += n_e * _ffn_params(cfg, cfg.expert_ff)
             p += cfg.d_model * cfg.n_experts  # router
         elif k == "ssm":
@@ -246,10 +272,14 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
             moe_d_ff=min(cfg.expert_ff, 128),
             first_k_dense=min(cfg.first_k_dense, 1),
         )
+        if cfg.experts_held:   # an expert-parallel share: 2 of 8 held
+            small.update(n_experts=8, experts_held=2, expert_offset=0)
+        if cfg.n_group > 1:
+            small.update(n_group=2, topk_group=1)
     if cfg.attn_kind == "mla":
         small.update(
-            q_lora_rank=64, kv_lora_rank=32, qk_rope_dim=16, qk_nope_dim=16,
-            v_head_dim=32, head_dim=0,
+            q_lora_rank=64 if cfg.q_lora_rank else 0, kv_lora_rank=32,
+            qk_rope_dim=16, qk_nope_dim=16, v_head_dim=32, head_dim=0,
         )
     if cfg.arch_type in ("ssm", "hybrid"):
         small.update(

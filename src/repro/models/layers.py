@@ -51,7 +51,8 @@ def init_norm(cfg: ModelConfig, rng, dim: Optional[int] = None):
     raise ValueError(cfg.norm)
 
 
-def apply_norm(cfg: ModelConfig, params, x, eps: float = 1e-6):
+def apply_norm(cfg: ModelConfig, params, x):
+    eps = cfg.norm_eps
     dt = x.dtype
     x32 = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
@@ -302,26 +303,34 @@ def apply_attention(cfg: ModelConfig, params, x, positions, *,
 # output) so the 32k-long cache is never re-expanded per step.
 
 def init_mla(cfg: ModelConfig, rng):
+    """With q_lora_rank 0 (Moonlight) the query is one projection
+    `w_q`; otherwise (DeepSeek-V3) it goes through the normed rank
+    q_lora_rank: `w_dq`, `q_norm`, `w_uq`."""
     d, h = cfg.d_model, cfg.n_heads
     r_q, r_kv = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     ks = jax.random.split(rng, 8)
     s = d ** -0.5
-    p = {
-        "w_dq": (jax.random.normal(ks[0], (d, r_q)) * s).astype(cfg.param_dtype),
-        "q_norm": jnp.ones((r_q,), cfg.param_dtype),
-        "w_uq": (jax.random.normal(ks[1], (r_q, h, dn + dr)) * r_q ** -0.5).astype(cfg.param_dtype),
+    if r_q:
+        p = {
+            "w_dq": (jax.random.normal(ks[0], (d, r_q)) * s).astype(cfg.param_dtype),
+            "q_norm": jnp.ones((r_q,), cfg.param_dtype),
+            "w_uq": (jax.random.normal(ks[1], (r_q, h, dn + dr)) * r_q ** -0.5).astype(cfg.param_dtype),
+        }
+    else:
+        p = {"w_q": (jax.random.normal(ks[0], (d, h, dn + dr)) * s).astype(cfg.param_dtype)}
+    p.update({
         "w_dkv": (jax.random.normal(ks[2], (d, r_kv)) * s).astype(cfg.param_dtype),
         "kv_norm": jnp.ones((r_kv,), cfg.param_dtype),
         "w_kr": (jax.random.normal(ks[3], (d, dr)) * s).astype(cfg.param_dtype),
         "w_uk": (jax.random.normal(ks[4], (r_kv, h, dn)) * r_kv ** -0.5).astype(cfg.param_dtype),
         "w_uv": (jax.random.normal(ks[5], (r_kv, h, dv)) * r_kv ** -0.5).astype(cfg.param_dtype),
         "wo": (jax.random.normal(ks[6], (h, dv, d)) * (h * dv) ** -0.5).astype(cfg.param_dtype),
-    }
+    })
     return p
 
 
-def _mla_norm(x, scale, eps=1e-6):
+def _mla_norm(x, scale, eps):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     return (x32 * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(x.dtype)
@@ -342,13 +351,20 @@ def apply_mla(cfg: ModelConfig, params, x, positions, *, theta,
     dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
     scale = (dn + dr) ** -0.5
 
-    cq = _mla_norm(jnp.einsum("bsd,dr->bsr", x, p["w_dq"]), p["q_norm"])
-    q = c(jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"]),
-          ("batch", "seq", "heads", "head_dim"))
+    eps = cfg.norm_eps
+
+    if cfg.q_lora_rank:
+        cq = _mla_norm(jnp.einsum("bsd,dr->bsr", x, p["w_dq"]), p["q_norm"],
+                       eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["w_q"])
+    q = c(q, ("batch", "seq", "heads", "head_dim"))
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, positions, theta)
 
-    c_kv = _mla_norm(jnp.einsum("bsd,dr->bsr", x, p["w_dkv"]), p["kv_norm"])
+    c_kv = _mla_norm(jnp.einsum("bsd,dr->bsr", x, p["w_dkv"]), p["kv_norm"],
+                     eps)
     k_r = apply_rope(jnp.einsum("bsd,dr->bsr", x, p["w_kr"]), positions, theta)
 
     new_cache = None
@@ -366,16 +382,17 @@ def apply_mla(cfg: ModelConfig, params, x, positions, *, theta,
         c_use, r_use = c_kv, k_r
 
     if s == 1 and cache is not None:
-        # Absorbed decode: scores in latent space, O(T * r_kv) per head set.
-        q_lat = _dotf32("bshk,rhk->bshr", q_nope, p["w_uk"]).astype(x.dtype)
-        scores = (_dotf32("bshr,btr->bhst", q_lat, c_use)
-                  + _dotf32("bshk,btk->bhst", q_rope, r_use)) * scale
-        mask = (kv_pos[:, None, :] <= positions[:, :, None])[:, None]  # (B,1,S,T)
-        scores = jnp.where(mask, scores, jnp.float32(-1e30))
-        w = jax.nn.softmax(scores, axis=-1)
-        o_lat = _dotf32("bhst,btr->bshr", w.astype(x.dtype), c_use)
-        out = _dotf32("bshr,rhv->bshv", o_lat.astype(x.dtype),
-                      p["w_uv"]).astype(x.dtype)
+        with jax.named_scope("mla.decode"):
+            # Absorbed decode: scores in latent space, O(T * r_kv) per head set.
+            q_lat = _dotf32("bshk,rhk->bshr", q_nope, p["w_uk"]).astype(x.dtype)
+            scores = (_dotf32("bshr,btr->bhst", q_lat, c_use)
+                      + _dotf32("bshk,btk->bhst", q_rope, r_use)) * scale
+            mask = (kv_pos[:, None, :] <= positions[:, :, None])[:, None]  # (B,1,S,T)
+            scores = jnp.where(mask, scores, jnp.float32(-1e30))
+            w = jax.nn.softmax(scores, axis=-1)
+            o_lat = _dotf32("bhst,btr->bshr", w.astype(x.dtype), c_use)
+            out = _dotf32("bshr,rhv->bshv", o_lat.astype(x.dtype),
+                          p["w_uv"]).astype(x.dtype)
     else:
         # Expanded form (train / prefill): chunked attention, MHA (rep=1).
         t = c_use.shape[1]

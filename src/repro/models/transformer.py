@@ -168,6 +168,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         if k_d:
             c["kv_dense"] = _zeros_tree(kv, dtype, (k_d,))
         c["kv_moe"] = _zeros_tree(kv, dtype, (cfg.n_layers - k_d,))
+        # the expert layers' counters (moe.py), accumulated on the device
+        # over every call through this cache
+        c["moe_stats"] = MOE.zero_stats()
         return c
     if at == "ssm":
         return {"ssm": _zeros_tree(ssm_shapes, jnp.float32, (cfg.n_layers,))}
@@ -210,7 +213,9 @@ def _layer_schedules(cfg: ModelConfig, kinds):
 
 def _attn_block(cfg, p, x, positions, theta, window, kv_cache, cache_index,
                 *, moe, mesh, constrain, enc_out=None, cross_cache=None,
-                rope=True):
+                rope=True, train=False):
+    """Returns (x, new_kv, new_cross, aux); with moe, aux is (aux loss,
+    the expert layer's counters)."""
     h = L.apply_norm(cfg, p["attn_norm"], x)
     if cfg.attn_kind == "mla":
         a, new_kv = L.apply_mla(cfg, p["attn"], h, positions, theta=theta,
@@ -233,7 +238,8 @@ def _attn_block(cfg, p, x, positions, theta, window, kv_cache, cache_index,
         new_cross = None
     h = L.apply_norm(cfg, p["mlp_norm"], x)
     if moe:
-        y, aux = MOE.apply_moe(cfg, p["ffn"], h, mesh, constrain=constrain)
+        y, loss, stats = MOE.apply_moe(cfg, p["ffn"], h, mesh, train=train)
+        aux = (loss, stats)
     else:
         y, aux = L.apply_mlp(cfg, p["ffn"], h), jnp.float32(0.0)
     x = constrain(x + y, ("batch", "seq", "embed"))
@@ -258,6 +264,8 @@ def _run_attn_stack(cfg, blocks, x, positions, cache, cache_index, kinds,
                     mesh, constrain, train, *, moe=False, enc_out=None,
                     cross_cache=None, rope=True):
     """Scan a stacked homogeneous attention stack. cache may be None.
+    Returns (x, aux, new_cache, counters); counters (moe.py) only for
+    an expert stack, else None.
 
     Cross-attention: during encdec prefill (enc_out given) the per-layer
     cross K/V are collected into ys so the caller can cache them; during
@@ -269,7 +277,7 @@ def _run_attn_stack(cfg, blocks, x, positions, cache, cache_index, kinds,
     emit_cross = has_cache and enc_out is not None
 
     def body(carry, xs):
-        x, aux = carry
+        x, aux = carry[:2]
         p = xs[0]
         theta, window = xs[1], xs[2]
         idx = 3
@@ -282,24 +290,28 @@ def _run_attn_stack(cfg, blocks, x, positions, cache, cache_index, kinds,
         x, new_kv, new_cross, a = _attn_block(
             cfg, p, x, positions, theta, window, kv, cache_index,
             moe=moe, mesh=mesh, constrain=constrain, enc_out=enc_out,
-            cross_cache=cc, rope=rope)
+            cross_cache=cc, rope=rope, train=train)
+        if moe:
+            carry = (x, aux + a[0], MOE.merge_stats(carry[2], a[1]))
+        else:
+            carry = (x, aux + a)
         if not has_cache:
             ys = 0
         elif emit_cross:
             ys = (new_kv, new_cross)
         else:
             ys = (new_kv,)
-        return (x, aux + a), ys
+        return carry, ys
 
     xs = [blocks, theta_arr, window_arr]
     if has_cache:
         xs.append(cache)
     if read_cross:
         xs.append(cross_cache)
-    (x, aux), ys = jax.lax.scan(_maybe_ckpt(cfg, train, body),
-                                (x, jnp.float32(0.0)), tuple(xs))
+    carry = (x, jnp.float32(0.0)) + ((MOE.zero_stats(),) if moe else ())
+    carry, ys = jax.lax.scan(_maybe_ckpt(cfg, train, body), carry, tuple(xs))
     new_cache = ys if has_cache else None
-    return x, aux, new_cache
+    return carry[0], carry[1], new_cache, (carry[2] if moe else None)
 
 
 def _run_ssm_stack(cfg, blocks, x, cache, constrain, train):
@@ -398,8 +410,9 @@ def _run_windowed_dense(cfg, params, x, positions, cache, cache_index,
 
 def _embed(cfg, params, tokens, constrain):
     e = params["embed"].astype(jnp.dtype(cfg.dtype))
-    x = jnp.take(e, tokens, axis=0) * jnp.asarray(
-        cfg.d_model ** 0.5, jnp.dtype(cfg.dtype))
+    x = jnp.take(e, tokens, axis=0)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, jnp.dtype(cfg.dtype))
     return constrain(x, ("batch", "seq", "embed"))
 
 
@@ -495,26 +508,28 @@ def forward(cfg: ModelConfig, params, batch, *, cache=None, cache_index=0,
                 constrain, train)
         else:
             kv = cache["kv"] if cache else None
-            x, aux, nkv = _run_attn_stack(cfg, params["blocks"], x, positions,
-                                          kv, cache_index, kinds, mesh,
-                                          constrain, train)
+            x, aux, nkv, _ = _run_attn_stack(
+                cfg, params["blocks"], x, positions, kv, cache_index, kinds,
+                mesh, constrain, train)
             if cache:
                 new_cache = {"kv": nkv[0]}
     elif at == "moe":
         k_d = cfg.first_k_dense
         if k_d:
             kvd = cache["kv_dense"] if cache else None
-            x, a1, nkvd = _run_attn_stack(
+            x, a1, nkvd, _ = _run_attn_stack(
                 cfg, params["dense_blocks"], x, positions, kvd, cache_index,
                 kinds[:k_d], mesh, constrain, train, moe=False)
             aux += a1
         kvm = cache["kv_moe"] if cache else None
-        x, a2, nkvm = _run_attn_stack(
+        x, a2, nkvm, stats = _run_attn_stack(
             cfg, params["moe_blocks"], x, positions, kvm, cache_index,
             kinds[k_d:], mesh, constrain, train, moe=True)
         aux += a2
         if cache:
-            new_cache = {"kv_moe": nkvm[0]}
+            new_cache = {"kv_moe": nkvm[0],
+                         "moe_stats": MOE.merge_stats(cache["moe_stats"],
+                                                      stats)}
             if k_d:
                 new_cache["kv_dense"] = nkvd[0]
     elif at == "ssm":
@@ -534,7 +549,7 @@ def forward(cfg: ModelConfig, params, batch, *, cache=None, cache_index=0,
             enc_out = None  # decode: use cached cross K/V
             cross_kv = cache["cross"]
         kv = cache["kv"] if cache else None
-        x, aux, ys = _run_attn_stack(
+        x, aux, ys, _ = _run_attn_stack(
             cfg, params["blocks"], x, positions, kv, cache_index, kinds,
             mesh, constrain, train, enc_out=enc_out,
             cross_cache=cross_kv, rope=False)

@@ -10,9 +10,9 @@ Workflow per Fig. 1 of the paper:
      simulated user preference is appended to the DB + ELO (the online,
      training-free update)
 
-The fleet here instantiates REDUCED configs of the assigned architectures
-(this is a CPU container); the production-mesh versions of the same step
-functions are what the dry-run lowers (launch/dryrun.py).
+Each fleet member is a FleetModel over any ModelConfig, at its published
+widths or a reduced test size; the production-mesh versions of the same
+step functions are what the dry-run lowers (launch/dryrun.py).
 """
 from __future__ import annotations
 
@@ -71,11 +71,17 @@ class FleetModel:
         self.cfg = cfg
         self.max_len = max_len
         self.params = T.init_params(cfg, jax.random.key(seed))
-        self._prefill = jax.jit(
-            lambda p, b: T.prefill(cfg, p, b, max_len,
-                                   cache_dtype=jnp.float32))
-        self._decode = jax.jit(
-            lambda p, c, t, i: T.decode_step(cfg, p, c, t, i))
+
+        # named, so the device trace's programs read jit_prefill and
+        # jit_decode_step
+        def prefill(p, b):
+            return T.prefill(cfg, p, b, max_len, cache_dtype=jnp.float32)
+
+        def decode_step(p, c, t, i):
+            return T.decode_step(cfg, p, c, t, i)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step)
 
     def generate(self, tokens: np.ndarray, max_new: int) -> np.ndarray:
         """tokens: (B, S) -> (B, max_new) greedy continuation.
@@ -85,7 +91,16 @@ class FleetModel:
         `serve.decode.<model>` (the decode loop). Each decode step is a
         ring-free marker
         `serve.decode_step.<model>` around the decode and
-        `serve.readout.<model>`, the host sync of the step's token."""
+        `serve.readout.<model>`, the host sync of the step's token.
+
+        A member with expert layers counts, on the device, over the
+        prefill and every decode step, and the counts are read once at
+        the end of `serve.decode.<model>` into `self.obs`'s registry:
+        `serve_moe_assignments_total{model}` (assignments routed to the
+        held experts), `serve_moe_dropped_total{model}` (of them not
+        computed: 0, serving is dropless) and the gauge
+        `serve_moe_max_expert_tokens{model}` (the busiest held expert's
+        rows in one layer call, the largest seen)."""
         ob = OBS.get_obs(self.obs)
         name = self.cfg.name
         step_span, readout_span = (f"serve.decode_step.{name}",
@@ -113,7 +128,22 @@ class FleetModel:
                     tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
                     with ob.span(readout_span, ring=False):
                         outs.append(np.asarray(tok)[:, 0])
+            if "moe_stats" in cache:
+                self._count_experts(ob, np.asarray(cache["moe_stats"]))
         return np.stack(outs, axis=1)
+
+    def _count_experts(self, ob, stats):
+        r, name = ob.registry, self.cfg.name
+        r.counter("serve_moe_assignments_total",
+                  "assignments routed to held experts",
+                  model=name).inc(int(stats[0]))
+        r.counter("serve_moe_dropped_total",
+                  "assignments to held experts not computed",
+                  model=name).inc(int(stats[1]))
+        top = r.gauge("serve_moe_max_expert_tokens",
+                      "busiest held expert's rows in one layer call",
+                      model=name)
+        top.set(max(top.value, int(stats[2])))
 
 
 class ServingEngine:

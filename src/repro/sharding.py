@@ -108,7 +108,7 @@ def param_rules(cfg: ModelConfig, mesh: Mesh, experts_2d: bool = False):
         (r"(attn|cross)/w[kv]$", [None, m_kv, None]),
         (r"(attn|cross)/wo$", [m_h, None, None]),
         # MLA
-        (r"attn/w_uq$", [None, m_h, None]),
+        (r"attn/w_u?q$", [None, m_h, None]),
         (r"attn/w_(uk|uv)$", [None, m_h, None]),
         (r"attn/w_(dq|dkv|kr)$", [None, None]),
         # dense ffn
@@ -199,6 +199,8 @@ def make_cache_specs(cfg: ModelConfig, mesh: Mesh, cache_shape: Pytree,
             return _right_align([b, seq_ax(leaf.shape[-3]), None, None], nd)
         if re.search(r"(c_kv|k_rope)$", s):
             return _right_align([b, seq_ax(leaf.shape[-2]), None], nd)
+        if re.search(r"moe_stats$", s):  # the expert layers' counters
+            return P(None)
         if re.search(r"(^|/)pos$", s):   # ring-buffer position tags (B, W)
             return _right_align([b, None], nd)
         if re.search(r"conv_x$", s):

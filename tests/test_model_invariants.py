@@ -120,22 +120,21 @@ def test_loss_mask_ignores_negative_targets(dense_setup):
 
 
 def test_moe_small_and_shardmap_paths_agree():
-    """The decode-path dense-dispatch MoE must match the pure _local_moe."""
+    """The serving path's dropless grouped matmul must match the
+    training path's capacity buffer where the capacity drops nothing."""
     from repro.models import moe as MOE
     cfg = get_reduced_config("phi3.5-moe-42b-a6.6b", capacity_factor=8.0)
     params = MOE.init_moe(cfg, jax.random.key(5))
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(size=(2, 4, cfg.d_model)) * 0.1, jnp.float32)
-    y_small, aux_s = MOE.apply_moe(cfg, params, x)   # T=8 -> small path
-    # reference: _local_moe single-shard path
-    routed = {k: params[k] for k in ("router", "w_gate", "w_up", "w_down")}
-    y_ref, aux_r = MOE._local_moe(cfg, routed, x.reshape(8, -1), None, 1, 0)
-    y_ref = y_ref.reshape(2, 4, -1)
-    if cfg.n_shared_experts:
-        pass  # reduced phi has no shared experts
-    np.testing.assert_allclose(np.asarray(y_small), np.asarray(y_ref),
+    y_serve, aux_s, st_s = MOE.apply_moe(cfg, params, x)
+    y_train, aux_t, st_t = MOE.apply_moe(cfg, params, x, train=True)
+    # reduced phi has no shared experts: both are the routed part alone
+    np.testing.assert_allclose(np.asarray(y_serve), np.asarray(y_train),
                                rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(float(aux_s), float(aux_r), rtol=1e-4)
+    np.testing.assert_allclose(float(aux_s), float(aux_t), rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(st_s), np.asarray(st_t))
+    assert int(st_s[0]) == 8 * cfg.experts_per_tok and int(st_s[1]) == 0
 
 
 def test_windowed_ring_cache_matches_full_cache():

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCH_IDS, get_reduced_config
+from repro.configs import ALL_IDS, get_reduced_config
 from repro.models import transformer as T
 
 jax.config.update("jax_platform_name", "cpu")
@@ -39,7 +39,7 @@ def rngkey():
     return jax.random.key(0)
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", ALL_IDS)
 def test_forward_and_loss(arch, rngkey):
     cfg = get_reduced_config(arch)
     params = T.init_params(cfg, rngkey)
@@ -57,7 +57,7 @@ def test_forward_and_loss(arch, rngkey):
     assert float(metrics["ce"]) > 0
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", ALL_IDS)
 def test_train_grad_step(arch, rngkey):
     cfg = get_reduced_config(arch)
     params = T.init_params(cfg, rngkey)
@@ -79,7 +79,7 @@ def test_train_grad_step(arch, rngkey):
     assert np.isfinite(loss2)
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
+@pytest.mark.parametrize("arch", ALL_IDS)
 def test_prefill_then_decode(arch, rngkey):
     cfg = get_reduced_config(arch)
     params = T.init_params(cfg, rngkey)
