@@ -538,34 +538,24 @@ class CapacityPrebaker:
         key on shapes, so the later real scatter is a hit; the dummy
         buffers are donated and freed immediately."""
         bucket = elo._pad_bucket(1)
+        width = STATE.packed_width(dim, records)
         mesh = self.dispatch.mesh
         if mesh is None:
-            panels = (jnp.zeros((capacity, dim), jnp.float32),
-                      jnp.zeros((capacity, records), jnp.int32),
-                      jnp.zeros((capacity, records), jnp.int32),
-                      jnp.zeros((capacity, records), jnp.float32),
-                      jnp.zeros((capacity, records), bool))
             STATE._scatter_rows(
-                *panels, jnp.zeros((bucket,), jnp.int32),
-                jnp.zeros((bucket, dim), jnp.float32),
-                jnp.zeros((bucket, records), jnp.int32),
-                jnp.zeros((bucket, records), jnp.int32),
-                jnp.zeros((bucket, records), jnp.float32),
-                jnp.zeros((bucket, records), bool))
+                jnp.zeros((capacity, dim), jnp.float32),
+                jnp.zeros((capacity, records), jnp.int32),
+                jnp.zeros((capacity, records), jnp.int32),
+                jnp.zeros((capacity, records), jnp.float32),
+                jnp.zeros((capacity, records), bool),
+                jnp.zeros((bucket, width), jnp.int32))
             return
         shards = SHARD.db_shard_count(mesh)
         shr = NamedSharding(mesh, P(SHARD.DB_AXIS))
         put = partial(jax.device_put, device=shr)
-        nb = shards * bucket
         STATE._sharded_scatter(mesh)(
             put(np.zeros((capacity, dim), np.float32)),
             put(np.zeros((capacity, records), np.int32)),
             put(np.zeros((capacity, records), np.int32)),
             put(np.zeros((capacity, records), np.float32)),
             put(np.zeros((capacity, records), bool)),
-            put(np.zeros((nb,), np.int32)),
-            put(np.zeros((nb, dim), np.float32)),
-            put(np.zeros((nb, records), np.int32)),
-            put(np.zeros((nb, records), np.int32)),
-            put(np.zeros((nb, records), np.float32)),
-            put(np.zeros((nb, records), bool)))
+            put(np.zeros((shards * bucket, width), np.int32)))

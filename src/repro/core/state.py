@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -133,16 +133,57 @@ def state_from_buffer(db, global_ratings,
     return jax.tree.map(jax.device_put, host, shardings)
 
 
-@partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
-def _scatter_rows(emb, model_a, model_b, outcome, valid, rows,
-                  emb_rows, a_rows, b_rows, o_rows, v_rows):
-    """Write the dirty rows into the donated previous-state buffers."""
+def packed_width(dim: int, records: int) -> int:
+    """32-bit words of one packed commit row (_pack_rows)."""
+    return dim + 4 * records + 2
+
+
+def _pack_rows(db, rows: np.ndarray, local: np.ndarray) -> np.ndarray:
+    """The dirty rows of the host buffer as ONE int32 (len(rows), W)
+    buffer, so a commit makes one transfer (per chip on a mesh). A row
+    is `[emb (D) | model_a (R) | model_b (R) | outcome (R) | valid (R)
+    | local row | live size]`: every field is 4 bytes wide, so the
+    floats travel as bit views (no value is converted: -0.0, NaN
+    payloads and denormals arrive as they left) and `valid` as 0/1.
+    emb leads so its slice starts on a lane boundary. `rows` index the
+    host buffer, `local` the target panel (the owning shard's on a
+    mesh)."""
+    d, r = db.emb.shape[1], db.model_a.shape[1]
+    buf = np.empty((rows.size, packed_width(d, r)), np.int32)
+    f32 = buf.view(np.float32)
+    f32[:, :d] = db.emb[rows]
+    buf[:, d:d + r] = db.model_a[rows]
+    buf[:, d + r:d + 2 * r] = db.model_b[rows]
+    f32[:, d + 2 * r:d + 3 * r] = db.outcome[rows]
+    buf[:, d + 3 * r:d + 4 * r] = db.valid[rows]
+    buf[:, -2] = local
+    buf[:, -1] = db.size
+    return buf
+
+
+def _write_rows(emb, model_a, model_b, outcome, valid, packed):
+    """Unpack a _pack_rows buffer on the device and write its rows into
+    the panels; the live size rides along in the header."""
+    d, r = emb.shape[1], model_a.shape[1]
+
+    def f32(x):
+        return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+    rows = packed[:, -2]
     with jax.named_scope("eagle.commit_scatter"):
-        return (emb.at[rows].set(emb_rows),
-                model_a.at[rows].set(a_rows),
-                model_b.at[rows].set(b_rows),
-                outcome.at[rows].set(o_rows),
-                valid.at[rows].set(v_rows))
+        return (emb.at[rows].set(f32(packed[:, :d])),
+                model_a.at[rows].set(packed[:, d:d + r]),
+                model_b.at[rows].set(packed[:, d + r:d + 2 * r]),
+                outcome.at[rows].set(f32(packed[:, d + 2 * r:d + 3 * r])),
+                valid.at[rows].set(packed[:, d + 3 * r:d + 4 * r] != 0),
+                packed[0, -1])
+
+
+@partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
+def _scatter_rows(emb, model_a, model_b, outcome, valid, packed):
+    """Write the packed dirty rows into the donated previous-state
+    buffers; returns the five panels and the live size."""
+    return _write_rows(emb, model_a, model_b, outcome, valid, packed)
 
 
 # ---------------------------------------------------------------------------
@@ -168,51 +209,52 @@ _SHARDED_SCATTER: Dict[Mesh, "jax.stages.Wrapped"] = {}
 
 
 def _sharded_scatter(mesh: Mesh):
-    """Jitted owner-scatter for a DB mesh, cached per mesh. Inputs are
-    per-shard stacks sharded over DB_AXIS — shard s receives ONLY the
-    rows it owns (local indices + payload), so each dirty row crosses
-    the host boundary toward exactly one device. Padding entries repeat
-    a row the shard owns with that row's host content, which makes the
-    duplicate writes idempotent (same guarantee the unsharded scatter's
-    repeat-first-row padding relies on)."""
+    """Jitted owner-scatter for a DB mesh, cached per mesh. The packed
+    rows (_pack_rows) are a per-shard stack sharded over DB_AXIS —
+    shard s receives ONLY the rows it owns, so each dirty row crosses
+    the host boundary toward exactly one device, in one transfer a
+    chip. Padding entries repeat a row the shard owns with that row's
+    host content, which makes the duplicate writes idempotent (same
+    guarantee the unsharded scatter's repeat-first-row padding relies
+    on). The live size leaves replicated."""
     fn = _SHARDED_SCATTER.get(mesh)
     if fn is not None:
         return fn
     spec = P(SHARD.DB_AXIS)
 
-    def body(emb, model_a, model_b, outcome, valid, rows,
-             emb_rows, a_rows, b_rows, o_rows, v_rows):
-        with jax.named_scope("eagle.commit_scatter"):
-            return (emb.at[rows].set(emb_rows),
-                    model_a.at[rows].set(a_rows),
-                    model_b.at[rows].set(b_rows),
-                    outcome.at[rows].set(o_rows),
-                    valid.at[rows].set(v_rows))
+    # the function's name is the compiled module's (`jit_body`), by
+    # which bench/lib/readers.py finds the owner-scatter in a trace
+    def body(emb, model_a, model_b, outcome, valid, packed):
+        return _write_rows(emb, model_a, model_b, outcome, valid, packed)
 
-    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 11,
-                               out_specs=(spec,) * 5, check_vma=False),
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 6,
+                               out_specs=(spec,) * 5 + (P(),),
+                               check_vma=False),
                  donate_argnums=(0, 1, 2, 3, 4))
     _SHARDED_SCATTER[mesh] = fn
     return fn
 
 
 def _commit_sharded(db, global_ratings, prev: Optional[RouterState],
-                    consumer: str, mesh: Mesh, ob) -> RouterState:
-    """Sharded commit(): drain the ledger grouped by OWNING shard and
-    scatter each group only to its shard (donated buffers). Falls back
-    to a full sharded upload on a shape change, like the unsharded
-    path. Replicated leaves (ratings, size) are re-placed on the mesh
-    every commit so the state's shardings stay AOT-executable-stable."""
+                    consumer: str, mesh: Mesh, ob) -> Tuple[RouterState, str]:
+    """Sharded _commit(): drain the ledger grouped by OWNING shard and
+    scatter each group only to its shard (donated buffers), from one
+    packed transfer a chip. Falls back to a full sharded upload on a
+    shape change, like the unsharded path. The ratings are re-placed
+    replicated on the mesh every commit so the state's shardings stay
+    AOT-executable-stable."""
     shards = SHARD.check_db_mesh(mesh, db.capacity)
     per_shard = db.drain_dirty_sharded(consumer, shards)
     if (prev is None or prev.emb.shape != db.emb.shape
             or prev.model_a.shape != db.model_a.shape):
-        return state_from_buffer(db, global_ratings, state_shardings(mesh))
+        return (state_from_buffer(db, global_ratings, state_shardings(mesh)),
+                "full")
     rep = NamedSharding(mesh, P())
-    g = jax.device_put(jnp.asarray(global_ratings, jnp.float32), rep)
-    size = jax.device_put(jnp.int32(db.size), rep)
+    g = jnp.asarray(global_ratings, jnp.float32)
     if not any(r.size for r in per_shard):
-        return dataclasses.replace(prev, global_ratings=g, size=size)
+        return dataclasses.replace(
+            prev, global_ratings=jax.device_put(g, rep),
+            size=jax.device_put(jnp.int32(db.size), rep)), "ratings_only"
     c_local = db.capacity // shards
     with ob.span("state.commit.gather"):
         bucket = elo._pad_bucket(max(r.size for r in per_shard))
@@ -222,17 +264,16 @@ def _commit_sharded(db, global_ratings, prev: Optional[RouterState],
             rows[s, :r.size] = r
             rows[s, r.size:] = pad
         flat = rows.reshape(-1)
-        host = (flat % c_local, db.emb[flat], db.model_a[flat],
-                db.model_b[flat], db.outcome[flat], db.valid[flat])
+        host = _pack_rows(db, flat, flat % c_local)
     with ob.span("state.commit.upload"):
-        shr = NamedSharding(mesh, P(SHARD.DB_AXIS))
-        dev = [jax.device_put(x, shr) for x in host]
+        packed = jax.device_put(host, NamedSharding(mesh, P(SHARD.DB_AXIS)))
+        g = jax.device_put(g, rep)
     with ob.span("state.commit.scatter"):
-        emb, a, b, o, v = _sharded_scatter(mesh)(
+        emb, a, b, o, v, size = _sharded_scatter(mesh)(
             prev.emb, prev.model_a, prev.model_b, prev.outcome,
-            prev.valid, *dev)
+            prev.valid, packed)
     return RouterState(global_ratings=g, emb=emb, model_a=a, model_b=b,
-                       outcome=o, valid=v, size=size)
+                       outcome=o, valid=v, size=size), "packed"
 
 
 def commit(db, global_ratings, prev: Optional[RouterState] = None,
@@ -256,16 +297,24 @@ def commit(db, global_ratings, prev: Optional[RouterState] = None,
     dirty row is scattered only to its owning shard (DESIGN.md §12).
 
     An incremental commit runs as three spans on `obs`:
-    `state.commit.gather` (the dirty rows read from the host buffer),
-    `state.commit.upload` (their transfers) and `state.commit.scatter`
-    (the scatter's enqueue)."""
-    ob = OBS.get_obs(obs)
+    `state.commit.gather` (the dirty rows packed from the host buffer),
+    `state.commit.upload` (their one transfer, one a chip on a mesh)
+    and `state.commit.scatter` (the scatter's enqueue)."""
+    return _commit(db, global_ratings, prev, consumer, mesh,
+                   OBS.get_obs(obs))[0]
+
+
+def _commit(db, global_ratings, prev, consumer, mesh, ob
+            ) -> Tuple[RouterState, str]:
+    """commit(), and which path it took: "full" (a whole upload, on
+    the first commit or a shape change), "ratings_only" (no dirty row
+    left) or "packed" (the dirty rows in one transfer)."""
     if mesh is not None:
         return _commit_sharded(db, global_ratings, prev, consumer, mesh, ob)
     rows = db.drain_dirty(consumer)
     if (prev is None or prev.emb.shape != db.emb.shape
             or prev.model_a.shape != db.model_a.shape):
-        return state_from_buffer(db, global_ratings)
+        return state_from_buffer(db, global_ratings), "full"
     g = jnp.asarray(global_ratings, jnp.float32)
     if rows.size:
         # rollback/clear guard: a drained row at/past the live count is
@@ -275,23 +324,22 @@ def commit(db, global_ratings, prev: Optional[RouterState] = None,
         rows = rows[rows < db.size]
     if rows.size == 0:
         return dataclasses.replace(prev, global_ratings=g,
-                                   size=jnp.int32(db.size))
+                                   size=jnp.int32(db.size)), "ratings_only"
     with ob.span("state.commit.gather"):
         bucket = elo._pad_bucket(rows.size)
         # pad by repeating the first dirty row: duplicate scatter writes
         # of identical content are idempotent
         rows = np.concatenate([rows, np.full(bucket - rows.size, rows[0],
                                              rows.dtype)])
-        host = (rows, db.emb[rows], db.model_a[rows], db.model_b[rows],
-                db.outcome[rows], db.valid[rows])
+        host = _pack_rows(db, rows, rows)
     with ob.span("state.commit.upload"):
-        dev = [jnp.asarray(x) for x in host]
+        packed = jnp.asarray(host)
     with ob.span("state.commit.scatter"):
-        emb, a, b, o, v = _scatter_rows(
+        emb, a, b, o, v, size = _scatter_rows(
             prev.emb, prev.model_a, prev.model_b, prev.outcome,
-            prev.valid, *dev)
+            prev.valid, packed)
     return RouterState(global_ratings=g, emb=emb, model_a=a, model_b=b,
-                       outcome=o, valid=v, size=jnp.int32(db.size))
+                       outcome=o, valid=v, size=size), "packed"
 
 
 class DoubleBuffer:
@@ -324,6 +372,9 @@ class DoubleBuffer:
         self._g_backlog = r.gauge(
             "dbuf_dirty_backlog",
             "dirty rows pending in the back replica's ledger at commit")
+        self._m_paths = {p: r.counter(
+            "dbuf_commit_total", "double-buffer commits by path", path=p)
+            for p in ("packed", "full", "ratings_only")}
 
     @property
     def front(self) -> RouterState:
@@ -338,10 +389,11 @@ class DoubleBuffer:
         st, tag = self._back
         self._g_backlog.set(len(self.db._dirty.get(tag, ())))
         with self.obs.span("state.commit"):
-            new = commit(self.db, global_ratings, st, consumer=tag,
-                         mesh=self.mesh, obs=self.obs)
+            new, path = _commit(self.db, global_ratings, st, tag,
+                                self.mesh, self.obs)
         self._back, self._front = self._front, (new, tag)
         self._m_swaps.inc()
+        self._m_paths[path].inc()
         return self.front
 
 

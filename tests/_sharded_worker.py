@@ -14,13 +14,20 @@ single JSON report line covering the whole matrix —
     (budget-selector ties) — all must match the oracle bit for bit;
   * incremental sharded commit() vs the oracle commit, field by field,
     plus post-commit routing equality;
+  * the packed sharded commit vs the per-field commit it replaced
+    (tests/_commit_oracle.py), bit for bit, over a sequence of commits
+    in which some shards own no dirty row;
   * zero post-warmup XLA compiles per mesh shape across a
     route+feedback+commit steady-state loop (warmup includes REAL
     feedback+commit cycles: an empty-ledger commit never exercises the
     scatter, so counting before the first real cycle would charge its
     compile to the steady state);
+  * the same through RouteDispatcher's cached executable on a
+    DoubleBuffer's fronts: zero compiles, every front with the
+    capacity partition's shardings, every commit counted `packed`;
   * the named device scopes of the 4-shard route (both backends) and
-    of the owner-scatter, in their lowered HLO;
+    of the owner-scatter, and the owner-scatter's module name, in their
+    lowered HLO;
   * a DB wide enough that every shard's top-k takes two stages
     (kernels/similarity_topk.py panel_topk), through the dispatcher on
     each mesh and both backends: bitwise equal to one device, with
@@ -66,8 +73,11 @@ def main():
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from _commit_oracle import (commit_steps, differing, host_differing,
+                                per_field_commit)
+    from repro import obs as OBS
     from repro.core import elo, state as STATE
-    from repro.core.dispatch import CompileCounter
+    from repro.core.dispatch import CompileCounter, RouteDispatcher
     from repro.core.vectordb import VectorDB
     from repro.launch.mesh import make_db_mesh
 
@@ -157,6 +167,65 @@ def main():
             equal(want.topk_idx, got.topk_idx)
         report["commit"][str(s)] = fields
 
+    # -- packed sharded commit vs the per-field commit it replaced -------
+    report["packed_commit"] = {}
+    for s in MESHES:
+        db4 = VectorDB(D, capacity=CAP, records_per_query=RCAP)
+        db4.register_consumer("packed")
+        db4.register_consumer("oracle")
+        rng4 = np.random.default_rng(400 + s)
+        g = jax.numpy.asarray(ratings)
+        got = want = None
+        bad = {}
+        for k, step in enumerate(commit_steps(db4, rng4, M)):
+            g = g + 0.25 * k
+            got = STATE.commit(db4, g, got, consumer="packed",
+                               mesh=meshes[s])
+            want = per_field_commit(db4, g, want, "oracle", meshes[s])
+            diff = differing(want, got) + [
+                "host." + f for f in host_differing(db4, got)]
+            if diff:
+                bad[step] = diff
+        report["packed_commit"][str(s)] = bad
+
+    # -- steady state through the dispatcher's cached executable ---------
+    report["dbuf_steady"] = {}
+    for s in MESHES:
+        mesh = meshes[s]
+        want_sh = STATE.state_shardings(mesh)
+        db5 = VectorDB(D, capacity=CAP, records_per_query=RCAP)
+        rng5 = np.random.default_rng(500 + s)
+        _fill(db5, 70, rng5)
+        ob = OBS.Observability()
+        dbuf = STATE.DoubleBuffer(db5, ratings, obs=ob, mesh=mesh)
+        d = RouteDispatcher(costs, mesh=mesh, obs=ob)
+        d.warmup(dbuf.front, [8])
+        row = [70]
+
+        def window():
+            d.route_result(dbuf.front, q, budgets)
+            for _ in range(2):
+                db5.add(rng5.normal(size=(1, D)).astype(np.float32),
+                        [0], [1], [1.0], query_id=[row[0]])
+                row[0] += 1
+            return dbuf.commit(ratings)
+
+        for _ in range(2):
+            window()
+        same = True
+        with CompileCounter() as cc:
+            for _ in range(6):
+                front = window()
+                same &= all(getattr(front, f).sharding.is_equivalent_to(
+                    getattr(want_sh, f), getattr(front, f).ndim)
+                    for f in want_sh.__dataclass_fields__)
+            jax.block_until_ready(dbuf.front)
+        reg = ob.registry
+        report["dbuf_steady"][str(s)] = {
+            "compiles": cc.count, "shardings": bool(same),
+            "misses": d.cache_stats()["misses"],
+            "packed": reg.value("dbuf_commit_total", path="packed")}
+
     # -- steady state: zero post-warmup compiles per mesh shape ----------
     report["hot_compiles"] = {}
     for s in MESHES:
@@ -200,21 +269,18 @@ def main():
         report["scopes"][bk] = sorted(
             sc for sc in ("eagle.similarity", "eagle.topk", "eagle.gather",
                           "eagle.merge", "eagle.replay") if sc in txt)
-    rows = np.zeros((4, 2), np.int32)
     shr = NamedSharding(mesh, P(STATE.SHARD.DB_AXIS))
-    put = lambda x: jax.device_put(x, shr)  # noqa: E731
-    txt = STATE._sharded_scatter(mesh).lower(
+    packed = jax.device_put(
+        np.zeros((4 * 2, STATE.packed_width(D, RCAP)), np.int32), shr)
+    lowered = STATE._sharded_scatter(mesh).lower(
         sstate.emb, sstate.model_a, sstate.model_b, sstate.outcome,
-        sstate.valid, put(rows.reshape(-1)),
-        put(np.zeros((8, D), np.float32)), put(np.zeros((8, RCAP), np.int32)),
-        put(np.zeros((8, RCAP), np.int32)),
-        put(np.zeros((8, RCAP), np.float32)),
-        put(np.zeros((8, RCAP), bool))).as_text(debug_info=True)
-    report["scopes"]["commit"] = "eagle.commit_scatter" in txt
+        sstate.valid, packed)
+    report["scopes"]["commit"] = \
+        "eagle.commit_scatter" in lowered.as_text(debug_info=True)
+    report["scatter_module"] = \
+        lowered.compile().as_text().split()[1].rstrip(",")
 
     # -- wide DB: the two-stage top-k on every shard ----------------------
-    from repro import obs as OBS
-    from repro.core.dispatch import RouteDispatcher
     from repro.kernels.similarity_topk import two_stage_topk
 
     def counted(mesh, st, backend, queries, rounds=3):

@@ -381,3 +381,40 @@ def test_prebaker_zero_hot_compiles_across_growth():
     assert r.db.capacity > start_capacity, "loop never crossed a grow"
     assert r.db.size > start_capacity
     assert hot == 0, f"{hot} hot-path compiles across the growth"
+
+
+def test_steady_packed_commit_compiles_nothing_keeps_shardings():
+    """One device: after warm-up, windows of route + feedback + packed
+    commit compile nothing, every commit takes the packed path, and each
+    front's leaves keep the shardings of the state the dispatcher's
+    executable was built on, so that one executable runs on every front.
+    (The 1/2/4-shard meshes: tests/test_sharded_state.py.)"""
+    from repro import obs as OBS
+    r, rng = _router(seed=12, capacity=256, n_prompts=100)
+    ob = OBS.Observability()
+    dbuf = DoubleBuffer(r.db, r.global_ratings, obs=ob)
+    d = RouteDispatcher.for_router(r)
+    d.warmup(dbuf.front, batch_sizes=[8])
+    q = rng.normal(size=(8, 8)).astype(np.float32)
+    budgets = rng.uniform(0.5, 6.0, 8).astype(np.float32)
+    want = [(x.sharding, x._committed)
+            for x in jax.tree_util.tree_leaves(dbuf.front)]
+
+    def window(k):
+        d.route(dbuf.front, q, budgets)
+        r.update(rng.normal(size=(2, 8)).astype(np.float32), [0, 1], [2, 3],
+                 [1.0, 0.0], query_id=[1000 + 2 * k, 1001 + 2 * k])
+        return dbuf.commit(r.global_ratings)
+
+    for k in range(2):                 # the scatter's first compile
+        window(k)
+    with CompileCounter() as c:
+        for k in range(2, 8):
+            front = window(k)
+            got = [(x.sharding, x._committed)
+                   for x in jax.tree_util.tree_leaves(front)]
+            assert got == want, k
+        jax.block_until_ready(dbuf.front)
+    assert c.delta() == 0
+    assert d.cache_stats()["misses"] == 1
+    assert ob.registry.value("dbuf_commit_total", path="packed") == 8

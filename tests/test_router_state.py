@@ -9,12 +9,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _commit_oracle import (commit_steps, differing, host_differing,
+                            per_field_commit)
+from repro import obs as OBS
 from repro.core import elo
 from repro.core.router import (EagleConfig, EagleRouter, GlobalOnlyRouter,
                                LocalOnlyRouter, combine_scores,
                                select_within_budget)
-from repro.core.state import (RouterState, batch_scores, commit, init_state,
-                              route_batch, state_from_buffer)
+from repro.core.state import (DoubleBuffer, RouterState, batch_scores,
+                              commit, init_state, route_batch,
+                              state_from_buffer)
+from repro.core.vectordb import VectorDB
 from repro.kernels import ops
 
 jax.config.update("jax_platform_name", "cpu")
@@ -199,6 +204,64 @@ def test_commit_without_writes_refreshes_ratings_only():
     np.testing.assert_allclose(np.asarray(s2.global_ratings),
                                np.asarray(s1.global_ratings) + 10.0)
     np.testing.assert_allclose(np.asarray(s2.emb), np.asarray(s1.emb))
+
+
+def test_packed_commit_bitwise_equals_per_field_commit():
+    """Over a sequence of commits (a padded bucket, rows rewritten with
+    -0.0, NaN payloads, denormals and mixed `valid`, rolled-back rows
+    past the live count, a commit with no dirty row, a wider spread),
+    the packed one-transfer commit leaves every field bit for bit as
+    the per-field commit it replaced, and the live rows as the host
+    buffer holds them."""
+    db = VectorDB(16, capacity=128, records_per_query=6)
+    db.register_consumer("packed")
+    db.register_consumer("oracle")
+    rng = np.random.default_rng(21)
+    g = jnp.full((4,), 1500.0, jnp.float32)
+    got = want = None
+    for k, step in enumerate(commit_steps(db, rng, 4)):
+        g = g + 0.25 * k
+        got = commit(db, g, got, consumer="packed")
+        want = per_field_commit(db, g, want, "oracle")
+        assert not differing(want, got), step
+        assert not host_differing(db, got), step
+
+
+def test_double_buffer_counts_commit_paths():
+    """dbuf_commit_total{path}: incremental commits are `packed`, a
+    commit after the buffer grew is `full`, one with nothing dirty is
+    `ratings_only`."""
+    router, rng = _random_router(seed=9, capacity=64, n_prompts=40)
+    ob = OBS.Observability()
+    dbuf = DoubleBuffer(router.db, router.global_ratings, obs=ob)
+
+    def count(path):
+        return ob.registry.value("dbuf_commit_total", path=path)
+
+    def add(n):
+        router.update(rng.normal(size=(n, 8)).astype(np.float32),
+                      [0] * n, [1] * n, [1.0] * n,
+                      query_id=[1000 + router.db.size + i for i in range(n)])
+
+    for _ in range(3):
+        add(2)
+        dbuf.commit(router.global_ratings)
+    assert (count("packed"), count("full"), count("ratings_only")) == \
+        (3, 0, 0)
+    dbuf.commit(router.global_ratings)   # the other replica's backlog
+    dbuf.commit(router.global_ratings)   # nothing left for either
+    assert (count("packed"), count("ratings_only")) == (4, 1)
+    add(30)                              # past the 64-row capacity
+    assert router.db.capacity > 64
+    dbuf.commit(router.global_ratings)
+    dbuf.commit(router.global_ratings)
+    assert (count("packed"), count("full"), count("ratings_only")) == \
+        (4, 2, 1)
+    add(1)
+    dbuf.commit(router.global_ratings)
+    assert count("packed") == 5
+    full = state_from_buffer(router.db, router.global_ratings)
+    assert not differing(full, dbuf.front)
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,10 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))
+
+from bench.lib.readers import SHARD_SCATTER_MODULE  # noqa: E402
+
 _REPORT = {}
 
 MESHES = ("1", "2", "4")
@@ -78,6 +82,26 @@ def test_incremental_sharded_commit_matches_oracle():
         assert not bad, f"mesh {mesh}: commit diverged on {bad}"
 
 
+def test_packed_sharded_commit_bitwise_equals_per_field_commit():
+    """Over a sequence of commits (a padded bucket, shards that own no
+    dirty row, rows rewritten with -0.0, NaN payloads, denormals and
+    mixed `valid`, rolled-back rows past the live count, a commit with
+    no dirty row), the packed commit, one transfer a shard, leaves every
+    field bit for bit as the per-field commit it replaced, and the live
+    rows as the host buffer holds them: {step: differing fields}."""
+    assert report()["packed_commit"] == {m: {} for m in MESHES}
+
+
+def test_steady_commit_keeps_shardings_and_compiles_nothing():
+    """Through RouteDispatcher's cached route executable on a
+    DoubleBuffer: after warm-up, windows of route + feedback + packed
+    commit compile nothing, and every front keeps the capacity
+    partition's shardings, so the one executable runs on each."""
+    for mesh, got in report()["dbuf_steady"].items():
+        assert got == {"compiles": 0, "shardings": True, "misses": 1,
+                       "packed": 8}, (mesh, got)
+
+
 def test_zero_post_warmup_compiles_per_mesh():
     """Steady-state route+feedback+commit loops recompile nothing once
     warmed (warmup includes real feedback+commit cycles — the scatter
@@ -105,6 +129,8 @@ def test_sharded_programs_carry_the_stage_scopes():
                                      "eagle.gather", "eagle.merge",
                                      "eagle.replay"]), bk
     assert scopes["commit"] is True
+    # bench/lib/readers.py finds commit_device_ms.shard by this name
+    assert report()["scatter_module"] == SHARD_SCATTER_MODULE
 
 
 def test_two_stage_topk_shards_bit_identical_and_counted():

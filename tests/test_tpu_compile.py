@@ -175,7 +175,5 @@ def test_sharded_route_and_commit_compile_on_four_described_chips(topo):
     STATE._sharded_scatter(mesh).lower(
         _sds((cap, D), jnp.float32, shd), _sds((cap, R), jnp.int32, shd),
         _sds((cap, R), jnp.int32, shd), _sds((cap, R), jnp.float32, shd),
-        _sds((cap, R), bool, shd), _sds((rows,), jnp.int32, shd),
-        _sds((rows, D), jnp.float32, shd), _sds((rows, R), jnp.int32, shd),
-        _sds((rows, R), jnp.int32, shd), _sds((rows, R), jnp.float32, shd),
-        _sds((rows, R), bool, shd)).compile()
+        _sds((cap, R), bool, shd),
+        _sds((rows, STATE.packed_width(D, R)), jnp.int32, shd)).compile()

@@ -21,6 +21,7 @@ import pytest
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
+from bench.lib.readers import SCATTER_MODULE  # noqa: E402
 from bench.lib.tracing import Trace  # noqa: E402
 from repro import obs as OBS  # noqa: E402
 from repro.configs import get_reduced_config  # noqa: E402
@@ -91,11 +92,13 @@ def test_scatter_hlo_carries_commit_scope():
     r, _ = _router()
     st = STATE.state_from_buffer(r.db, r.global_ratings)
     rows = np.arange(4, dtype=np.int32)
-    txt = STATE._scatter_rows.lower(
-        st.emb, st.model_a, st.model_b, st.outcome, st.valid, rows,
-        r.db.emb[rows], r.db.model_a[rows], r.db.model_b[rows],
-        r.db.outcome[rows], r.db.valid[rows]).as_text(debug_info=True)
-    assert "eagle.commit_scatter" in txt
+    lowered = STATE._scatter_rows.lower(
+        st.emb, st.model_a, st.model_b, st.outcome, st.valid,
+        STATE._pack_rows(r.db, rows, rows))
+    assert "eagle.commit_scatter" in lowered.as_text(debug_info=True)
+    # bench/lib/readers.py finds commit_device_ms.route by this name
+    assert lowered.compile().as_text().split()[1].rstrip(",") == \
+        SCATTER_MODULE
 
 
 # ---------------------------------------------------------------------------
