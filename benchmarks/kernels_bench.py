@@ -41,8 +41,11 @@ def run(verbose=True):
     o = jnp.asarray(rng.choice([0., .5, 1.], (16384, 8)), jnp.float32)
     v = jnp.ones((16384, 8), bool)
     init = jnp.full((10,), 1000.0, jnp.float32)
-    us, _ = C.timer(lambda: ops.retrieve_replay(
-        q, db, a, b, o, v, jnp.int32(16384), init, n=20))
+    costs = jnp.ones((10,), jnp.float32)
+    budgets = jnp.full((64,), jnp.inf, jnp.float32)
+    us, _ = C.timer(lambda: ops.retrieve_replay_select(
+        q, db, a, b, o, v, jnp.int32(16384), init, init, costs, budgets,
+        n=20)[0])
     # similarity panel + 160-step replay over (64,10) one-hot tiles;
     # the panel matmul dominates
     rr_flops = 2 * 64 * 16384 * 1536 + 160 * 64 * 10 * 8

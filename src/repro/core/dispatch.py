@@ -12,8 +12,8 @@ path recompile-free at steady state:
     traffic;
   * each bucket's executable is AOT-compiled (jit.lower().compile())
     into an EVICTION-FREE cache keyed on
-    (batch_bucket, capacity, records_per_query, mode, backend) — the
-    full static signature of a dispatch. AOT executables bypass jit's
+    (batch_bucket, capacity, records_per_query, mode, backend, mesh) —
+    the full static signature of a dispatch. AOT executables bypass jit's
     tracing machinery entirely, so a cache hit is a direct XLA call and
     a compile can ONLY happen on a cache miss: `stats()` is an exact
     compile ledger, which the CI steady-state gate asserts over;
@@ -22,7 +22,10 @@ path recompile-free at steady state:
 
 The cached executable is route_batch_choices — the lean variant whose
 (Q, M) score panel never leaves the device (the budget selection is
-fused into the replay kernel's epilogue).
+fused into the replay kernel's epilogue). A dispatcher given a DB mesh
+lowers the same function with that mesh (the capacity-sharded route,
+DESIGN.md §12); the mesh is the one fork, and it is part of the cache
+key.
 """
 from __future__ import annotations
 
@@ -41,9 +44,7 @@ from repro import sharding as SHARD
 from repro.core import elo
 from repro.core import state as STATE
 from repro.core.state import (RouterState, route_batch_choices,
-                              route_batch_choices_sharded,
                               state_shardings)
-from repro.kernels.similarity_topk import two_stage_topk
 
 #: default bucket ladder bounds (powers of two, inclusive)
 MIN_BUCKET = 8
@@ -202,8 +203,6 @@ class RouteDispatcher:
         self.min_bucket = min_bucket
         self.max_bucket = max_bucket
         self._cache: Dict[Tuple, jax.stages.Compiled] = {}
-        # per cache key: whether that executable's top-k is two-stage
-        self._two_stage: Dict[Tuple, bool] = {}
         self._lock = threading.Lock()
         self.stats = DispatchStats()
         _ensure_listener()
@@ -219,9 +218,6 @@ class RouteDispatcher:
         self._m_padded = r.counter(
             "dispatch_padded_rows_total",
             "bucket-padded rows dispatched (>= rows; waste = padded-rows)")
-        self._m_two_stage = r.counter(
-            "dispatch_two_stage_topk_total",
-            "dispatches whose executable takes the two-stage top-k")
         self._m_hits = r.counter(
             "dispatch_cache_hits_total", "executable-cache hits")
         self._m_misses = r.counter(
@@ -289,15 +285,9 @@ class RouteDispatcher:
                     c = jax.ShapeDtypeStruct(self.costs.shape,
                                              jnp.float32,
                                              sharding=self._rep)
-                    if self.mesh is None:
-                        fn = route_batch_choices.lower(
-                            state, q, b, c, **self.kw).compile()
-                    else:
-                        fn = route_batch_choices_sharded.lower(
-                            state, q, b, c, mesh=self.mesh,
-                            **self.kw).compile()
+                    fn = route_batch_choices.lower(
+                        state, q, b, c, mesh=self.mesh, **self.kw).compile()
                 self._cache[key] = fn
-                self._two_stage[key] = self._takes_two_stage_topk(state)
                 self.stats.misses += 1
                 self.stats.warmed += bool(warm)
                 dt = time.perf_counter() - t0
@@ -309,17 +299,6 @@ class RouteDispatcher:
                                "records": state.records_per_query,
                                "seconds": dt})
         return fn
-
-    def _takes_two_stage_topk(self, state: RouterState) -> bool:
-        """Whether the route executable for `state`'s shapes reduces its
-        score panel with the two-stage top-k: a static function of the
-        rows per shard and the neighbours kept (panel_topk)."""
-        if self.kw["mode"] == "global":
-            return False                # no retrieval
-        shards = 1 if self.mesh is None else SHARD.db_shard_count(self.mesh)
-        c_local = state.capacity // shards
-        n = min(self.kw["n_neighbors"], state.capacity, c_local)
-        return two_stage_topk(c_local, n)
 
     def warmup(self, state: RouterState,
                batch_sizes: Optional[Sequence[int]] = None) -> int:
@@ -408,8 +387,6 @@ class RouteDispatcher:
                 b = jax.device_put(b, self._rep)
         with obs.span("dispatch.launch"):
             res = self._compiled(state, qb)(state, q, b, self.costs)
-        if self._two_stage[self._key(state, qb)]:
-            self._m_two_stage.inc()
         return res, nq
 
     def _route_one(self, state: RouterState, q: np.ndarray,
@@ -534,28 +511,20 @@ class CapacityPrebaker:
 
     def _warm_scatter(self, capacity: int, records: int, dim: int):
         """Execute one dummy commit scatter at the next-capacity shapes
-        (the smallest row bucket — the common case). jit call caches
-        key on shapes, so the later real scatter is a hit; the dummy
+        (the smallest row bucket — the common case), placed as the
+        commit places them: on one device, or split over the DB mesh
+        (one shard without a mesh). jit call caches key on shapes and
+        shardings, so the later real scatter is a hit; the dummy
         buffers are donated and freed immediately."""
-        bucket = elo._pad_bucket(1)
-        width = STATE.packed_width(dim, records)
         mesh = self.dispatch.mesh
-        if mesh is None:
-            STATE._scatter_rows(
-                jnp.zeros((capacity, dim), jnp.float32),
-                jnp.zeros((capacity, records), jnp.int32),
-                jnp.zeros((capacity, records), jnp.int32),
-                jnp.zeros((capacity, records), jnp.float32),
-                jnp.zeros((capacity, records), bool),
-                jnp.zeros((bucket, width), jnp.int32))
-            return
-        shards = SHARD.db_shard_count(mesh)
-        shr = NamedSharding(mesh, P(SHARD.DB_AXIS))
-        put = partial(jax.device_put, device=shr)
-        STATE._sharded_scatter(mesh)(
-            put(np.zeros((capacity, dim), np.float32)),
-            put(np.zeros((capacity, records), np.int32)),
-            put(np.zeros((capacity, records), np.int32)),
-            put(np.zeros((capacity, records), np.float32)),
-            put(np.zeros((capacity, records), bool)),
-            put(np.zeros((shards * bucket, width), np.int32)))
+        shards = 1 if mesh is None else SHARD.db_shard_count(mesh)
+        zeros = partial(jnp.zeros, device=None if mesh is None else
+                        NamedSharding(mesh, P(SHARD.DB_AXIS)))
+        rows = shards * elo._pad_bucket(1)
+        STATE.scatter_for(mesh)(
+            zeros((capacity, dim), jnp.float32),
+            zeros((capacity, records), jnp.int32),
+            zeros((capacity, records), jnp.int32),
+            zeros((capacity, records), jnp.float32),
+            zeros((capacity, records), bool),
+            zeros((rows, STATE.packed_width(dim, records)), jnp.int32))

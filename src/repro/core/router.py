@@ -17,7 +17,7 @@ EagleRouter is a thin stateful shell over the functional core in
 core/state.py: writes (fit/update/feedback) land in the host append
 buffer + global ratings and lazily commit into a device-resident
 RouterState; reads (scores/rank/route) are single jitted dispatches of
-route_batch/batch_scores over that state.
+route_batch over that state.
 """
 from __future__ import annotations
 
@@ -31,9 +31,8 @@ import numpy as np
 
 from repro import obs as OBS
 from repro.core import elo
-from repro.core.state import (RouterState, RouteResult, batch_scores,
-                              combine_scores, commit, route_batch,
-                              select_within_budget)
+from repro.core.state import (RouterState, RouteResult, combine_scores,
+                              commit, route_batch, select_within_budget)
 from repro.core.vectordb import VectorDB
 
 __all__ = ["EagleConfig", "EagleRouter", "GlobalOnlyRouter",
@@ -136,7 +135,8 @@ class EagleRouter:
     # -- scoring (single-dispatch reads over the committed state) ------------
     def scores(self, query_emb) -> jnp.ndarray:
         """(Q, M) combined quality scores (higher = better predicted)."""
-        return batch_scores(self.state, query_emb, **self._kw())
+        return route_batch(self.state, query_emb, jnp.inf, self.costs,
+                           **self._kw()).scores
 
     def rank(self, query_emb) -> jnp.ndarray:
         """(Q, M) model indices, best first."""
@@ -156,10 +156,12 @@ class EagleRouter:
         from repro.kernels import ops as KOPS
         s = self.state
         q = jnp.atleast_2d(jnp.asarray(query_emb, jnp.float32))
-        local, _, _ = KOPS.retrieve_replay(
+        local, _, _, _ = KOPS.retrieve_replay_select(
             q, s.emb, s.model_a, s.model_b, s.outcome, s.valid, s.size,
-            s.global_ratings, n=min(self.cfg.n_neighbors, s.capacity),
-            k=self.cfg.k_factor, backend=self.cfg.backend)
+            s.global_ratings, s.global_ratings, self.costs,
+            jnp.full((q.shape[0],), jnp.inf, jnp.float32),
+            n=min(self.cfg.n_neighbors, s.capacity), k=self.cfg.k_factor,
+            backend=self.cfg.backend)
         return local
 
     # -- feedback loop (workflow step 5) ------------------------------------

@@ -5,7 +5,7 @@ path needs on device: the standing global ELO ratings plus the vector-DB
 panels (embeddings + grouped pairwise feedback). Per-batch routing is ONE
 jitted dispatch over this state:
 
-    route_batch(state, query_embs, budgets, costs)
+    route_batch_choices(state, query_embs, budgets, costs, mesh=None)
       = similarity -> top-k -> record gather -> local ELO replay
         -> score combine -> budget masking
 
@@ -15,6 +15,14 @@ times per batch). The VectorDB stays a host-side append buffer — appends
 must cost microseconds — and syncs into a RouterState via commit(), which
 scatters only the rows touched since the last commit into the previous
 state's DONATED device buffers (O(new records) upload, no realloc).
+
+The route and the commit each have one body (_route, _commit). The only
+fork is whether a DB `mesh` is given: with one, the state is
+capacity-sharded, the retrieval chain runs under shard_map with a
+cross-shard merge, and each dirty row is scattered only to its owning
+shard (DESIGN.md §12); one device behaves as a mesh of one shard.
+route_batch is the object API's variant of the same body, which also
+returns the (Q, M) scores.
 
 EagleRouter (core/router.py) is a thin stateful shell over these
 functions; ServingEngine and the benchmarks call them directly.
@@ -187,7 +195,7 @@ def _scatter_rows(emb, model_a, model_b, outcome, valid, packed):
 
 
 # ---------------------------------------------------------------------------
-# capacity-sharded state: placement, routing, commit (DESIGN.md §12)
+# placement on a DB mesh (DESIGN.md §12), the scatters, the commit
 # ---------------------------------------------------------------------------
 
 def state_shardings(mesh: Mesh) -> RouterState:
@@ -213,10 +221,7 @@ def _sharded_scatter(mesh: Mesh):
     rows (_pack_rows) are a per-shard stack sharded over DB_AXIS —
     shard s receives ONLY the rows it owns, so each dirty row crosses
     the host boundary toward exactly one device, in one transfer a
-    chip. Padding entries repeat a row the shard owns with that row's
-    host content, which makes the duplicate writes idempotent (same
-    guarantee the unsharded scatter's repeat-first-row padding relies
-    on). The live size leaves replicated."""
+    chip. The live size leaves replicated."""
     fn = _SHARDED_SCATTER.get(mesh)
     if fn is not None:
         return fn
@@ -235,45 +240,11 @@ def _sharded_scatter(mesh: Mesh):
     return fn
 
 
-def _commit_sharded(db, global_ratings, prev: Optional[RouterState],
-                    consumer: str, mesh: Mesh, ob) -> Tuple[RouterState, str]:
-    """Sharded _commit(): drain the ledger grouped by OWNING shard and
-    scatter each group only to its shard (donated buffers), from one
-    packed transfer a chip. Falls back to a full sharded upload on a
-    shape change, like the unsharded path. The ratings are re-placed
-    replicated on the mesh every commit so the state's shardings stay
-    AOT-executable-stable."""
-    shards = SHARD.check_db_mesh(mesh, db.capacity)
-    per_shard = db.drain_dirty_sharded(consumer, shards)
-    if (prev is None or prev.emb.shape != db.emb.shape
-            or prev.model_a.shape != db.model_a.shape):
-        return (state_from_buffer(db, global_ratings, state_shardings(mesh)),
-                "full")
-    rep = NamedSharding(mesh, P())
-    g = jnp.asarray(global_ratings, jnp.float32)
-    if not any(r.size for r in per_shard):
-        return dataclasses.replace(
-            prev, global_ratings=jax.device_put(g, rep),
-            size=jax.device_put(jnp.int32(db.size), rep)), "ratings_only"
-    c_local = db.capacity // shards
-    with ob.span("state.commit.gather"):
-        bucket = elo._pad_bucket(max(r.size for r in per_shard))
-        rows = np.empty((shards, bucket), np.int32)   # GLOBAL row ids
-        for s, r in enumerate(per_shard):
-            pad = r[0] if r.size else s * c_local   # a row shard s owns
-            rows[s, :r.size] = r
-            rows[s, r.size:] = pad
-        flat = rows.reshape(-1)
-        host = _pack_rows(db, flat, flat % c_local)
-    with ob.span("state.commit.upload"):
-        packed = jax.device_put(host, NamedSharding(mesh, P(SHARD.DB_AXIS)))
-        g = jax.device_put(g, rep)
-    with ob.span("state.commit.scatter"):
-        emb, a, b, o, v, size = _sharded_scatter(mesh)(
-            prev.emb, prev.model_a, prev.model_b, prev.outcome,
-            prev.valid, packed)
-    return RouterState(global_ratings=g, emb=emb, model_a=a, model_b=b,
-                       outcome=o, valid=v, size=size), "packed"
+def scatter_for(mesh: Optional[Mesh]):
+    """The commit's scatter: _scatter_rows on one device (module
+    `jit__scatter_rows`), the owner-scatter on a DB mesh (`jit_body`);
+    bench/lib/readers.py reads each by its module name."""
+    return _scatter_rows if mesh is None else _sharded_scatter(mesh)
 
 
 def commit(db, global_ratings, prev: Optional[RouterState] = None,
@@ -308,34 +279,47 @@ def _commit(db, global_ratings, prev, consumer, mesh, ob
             ) -> Tuple[RouterState, str]:
     """commit(), and which path it took: "full" (a whole upload, on
     the first commit or a shape change), "ratings_only" (no dirty row
-    left) or "packed" (the dirty rows in one transfer)."""
-    if mesh is not None:
-        return _commit_sharded(db, global_ratings, prev, consumer, mesh, ob)
-    rows = db.drain_dirty(consumer)
+    left) or "packed" (the dirty rows in one transfer a chip).
+
+    One device is a mesh of one shard here: the ledger drains grouped
+    by owning shard (drain_dirty_sharded, which drops stale rows at or
+    past the live count — the rollback/clear guard), and each shard's
+    rows pad to the bucket by repeating a row that shard owns, with
+    that row's host content, so the duplicate writes are idempotent.
+    Only the placement differs: as is on one device, the ratings and
+    size replicated and the packed rows split by shard on a mesh. The
+    ratings are re-placed every commit so the state's shardings stay
+    AOT-executable-stable."""
+    shards = 1 if mesh is None else SHARD.check_db_mesh(mesh, db.capacity)
+    per_shard = db.drain_dirty_sharded(consumer, shards)
     if (prev is None or prev.emb.shape != db.emb.shape
             or prev.model_a.shape != db.model_a.shape):
-        return state_from_buffer(db, global_ratings), "full"
-    g = jnp.asarray(global_ratings, jnp.float32)
-    if rows.size:
-        # rollback/clear guard: a drained row at/past the live count is
-        # stale (its content is masked by `size` anyway) — drop it
-        # rather than scatter it, and never index rows[0] of what could
-        # now be an empty set.
-        rows = rows[rows < db.size]
-    if rows.size == 0:
-        return dataclasses.replace(prev, global_ratings=g,
-                                   size=jnp.int32(db.size)), "ratings_only"
+        return (state_from_buffer(
+            db, global_ratings,
+            None if mesh is None else state_shardings(mesh)), "full")
+
+    def put(x, spec):
+        return jnp.asarray(x) if mesh is None else \
+            jax.device_put(x, NamedSharding(mesh, spec))
+
+    g = put(jnp.asarray(global_ratings, jnp.float32), P())
+    if not any(r.size for r in per_shard):
+        return dataclasses.replace(
+            prev, global_ratings=g,
+            size=put(np.int32(db.size), P())), "ratings_only"
+    c_local = db.capacity // shards
     with ob.span("state.commit.gather"):
-        bucket = elo._pad_bucket(rows.size)
-        # pad by repeating the first dirty row: duplicate scatter writes
-        # of identical content are idempotent
-        rows = np.concatenate([rows, np.full(bucket - rows.size, rows[0],
-                                             rows.dtype)])
-        host = _pack_rows(db, rows, rows)
+        bucket = elo._pad_bucket(max(r.size for r in per_shard))
+        rows = np.empty((shards, bucket), np.int32)   # GLOBAL row ids
+        for s, r in enumerate(per_shard):
+            rows[s, :r.size] = r
+            rows[s, r.size:] = r[0] if r.size else s * c_local
+        flat = rows.reshape(-1)
+        host = _pack_rows(db, flat, flat % c_local)
     with ob.span("state.commit.upload"):
-        packed = jnp.asarray(host)
+        packed = put(host, P(SHARD.DB_AXIS))
     with ob.span("state.commit.scatter"):
-        emb, a, b, o, v, size = _scatter_rows(
+        emb, a, b, o, v, size = scatter_for(mesh)(
             prev.emb, prev.model_a, prev.model_b, prev.outcome,
             prev.valid, packed)
     return RouterState(global_ratings=g, emb=emb, model_a=a, model_b=b,
@@ -412,47 +396,17 @@ class RouteChoices(NamedTuple):
     topk_idx: jax.Array   # (Q, N) retrieved prompt rows (-1 in global mode)
 
 
-def _scores(state: RouterState, q, p_global, n_neighbors, k, backend,
-            mode, init_rating):
-    if mode not in MODES:
-        raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
-    q = jnp.atleast_2d(jnp.asarray(q, jnp.float32))
-    nq = q.shape[0]
-    m = state.n_models
-    n = min(n_neighbors, state.capacity)
-    if mode == "global":
-        # Eagle-Global ablation: no retrieval at all
-        scores = jnp.broadcast_to(state.global_ratings, (nq, m))
-        return scores, jnp.full((nq, n), -1, jnp.int32)
-    if mode == "local":
-        init = jnp.full((m,), jnp.float32(init_rating))  # flat prior
-    else:
-        init = state.global_ratings
-    local, top_i, _ = KOPS.retrieve_replay(
-        q, state.emb, state.model_a, state.model_b, state.outcome,
-        state.valid, state.size, init, n=n, k=k, backend=backend)
-    if mode == "local":
-        return local, top_i
-    return combine_scores(state.global_ratings, local, p_global), top_i
-
-
-@partial(jax.jit,
-         static_argnames=("n_neighbors", "k", "backend", "mode"))
-def batch_scores(state: RouterState, query_embs, *, p_global: float = 0.5,
-                 n_neighbors: int = 20, k: float = 32.0,
-                 backend: str = "reference", mode: str = "combined",
-                 init_rating: float = elo.DEFAULT_RATING):
-    """(Q, M) combined quality scores, one jitted dispatch."""
-    return _scores(state, query_embs, p_global, n_neighbors, k, backend,
-                   mode, init_rating)[0]
-
-
-def _route(state: RouterState, q, budgets, costs, p_global, n_neighbors,
-           k, backend, mode, init_rating):
-    """Shared body of route_batch/route_batch_choices: the retrieval +
-    replay + budget-selection chain with the selection folded into the
-    kernel epilogue (choices leave the replay tile directly; the
-    standalone select_within_budget stays as the parity oracle)."""
+def _route(state: RouterState, q, budgets, costs, *, p_global,
+           n_neighbors, k, backend, mode, init_rating,
+           mesh: Optional[Mesh] = None) -> RouteResult:
+    """The one body of route_batch and route_batch_choices: the
+    retrieval + replay + budget-selection chain with the selection
+    folded into the kernel epilogue (choices leave the replay tile
+    directly; the standalone select_within_budget stays as the parity
+    oracle). With a DB `mesh` the chain runs under shard_map over the
+    DB axis — per-shard similarity + local top-k, cross-shard candidate
+    merge, replicated replay and selection — and the scores stay on the
+    shards (None)."""
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     q = jnp.atleast_2d(jnp.asarray(q, jnp.float32))
@@ -463,26 +417,44 @@ def _route(state: RouterState, q, budgets, costs, p_global, n_neighbors,
     budgets = jnp.broadcast_to(jnp.asarray(budgets, jnp.float32), (nq,))
     if mode == "global":
         # Eagle-Global ablation: no retrieval, selection is the whole op
+        # (ratings replicate on a mesh, so no shard_map either)
         scores = jnp.broadcast_to(state.global_ratings, (nq, m))
         choices, _ = select_within_budget(scores, costs, budgets)
-        return choices, scores, jnp.full((nq, n), -1, jnp.int32)
+        return RouteResult(choices, scores, jnp.full((nq, n), -1, jnp.int32))
     if mode == "local":
         init = jnp.full((m,), jnp.float32(init_rating))  # flat prior
         p = 0.0   # 0*Global + 1*Local == Local, bit-exact for finite r
     else:
         init = state.global_ratings
         p = p_global
+    op = partial(KOPS.retrieve_replay_select, n=n, k=k, p=p,
+                 backend=backend)
+    args = (q, state.emb, state.model_a, state.model_b, state.outcome,
+            state.valid, state.size, init, state.global_ratings, costs,
+            budgets)
     # named_scope tags the fused chain's HLO ops so device ops group
     # under one label in XLA profiles, next to the host-side
     # TraceAnnotation spans the tracer emits around the dispatch
     with jax.named_scope("eagle.retrieve_replay_select"):
-        local, top_i, _, choices = KOPS.retrieve_replay_select(
-            q, state.emb, state.model_a, state.model_b, state.outcome,
-            state.valid, state.size, init, state.global_ratings, costs,
-            budgets, n=n, k=k, p=p, backend=backend)
+        if mesh is None:
+            local, top_i, _, choices = op(*args)
+        else:
+            def body(*a):
+                _, top_i, _, choices = op(*a, axis_name=SHARD.DB_AXIS)
+                return choices, top_i
+
+            shd, rep = P(SHARD.DB_AXIS), P()
+            # check_vma=False: the merged epilogue output is replicated
+            # by construction (every shard reduces the same gathered
+            # pool), which shard_map's replication checker cannot prove
+            # through all_gather
+            choices, top_i = jax.shard_map(
+                body, mesh=mesh, in_specs=(rep,) + (shd,) * 5 + (rep,) * 5,
+                out_specs=(rep, rep), check_vma=False)(*args)
+            return RouteResult(choices, None, top_i)
     scores = local if mode == "local" else \
         combine_scores(state.global_ratings, local, p_global)
-    return choices, scores, top_i
+    return RouteResult(choices, scores, top_i)
 
 
 @partial(jax.jit,
@@ -493,91 +465,39 @@ def route_batch(state: RouterState, query_embs, budgets, costs, *,
                 k: float = 32.0, backend: str = "reference",
                 mode: str = "combined",
                 init_rating: float = elo.DEFAULT_RATING) -> RouteResult:
-    """Route a batch of queries under budgets: the entire hot path —
-    similarity, top-k, feedback gather, local ELO replay, score
-    combination, budget masking — fused into a single device dispatch,
-    with the budget selection folded into the replay kernel's epilogue."""
-    choices, scores, top_i = _route(state, query_embs, budgets, costs,
-                                    p_global, n_neighbors, k, backend,
-                                    mode, init_rating)
-    return RouteResult(choices, scores, top_i)
-
-
-@partial(jax.jit,
-         static_argnames=("p_global", "n_neighbors", "k", "backend",
-                          "mode", "init_rating"))
-def route_batch_choices(state: RouterState, query_embs, budgets, costs, *,
-                        p_global: float = 0.5, n_neighbors: int = 20,
-                        k: float = 32.0, backend: str = "reference",
-                        mode: str = "combined",
-                        init_rating: float = elo.DEFAULT_RATING
-                        ) -> RouteChoices:
-    """Lean serving variant of route_batch: identical dataflow, but the
-    (Q, M) score panel is never an output — only the fused-epilogue
-    choices and the retrieval trace leave the device. This is what the
-    dispatch cache (core/dispatch.py) pre-compiles per bucket."""
-    choices, _, top_i = _route(state, query_embs, budgets, costs,
-                               p_global, n_neighbors, k, backend, mode,
-                               init_rating)
-    return RouteChoices(choices, top_i)
+    """Route a batch of queries under budgets on one device: the entire
+    hot path — similarity, top-k, feedback gather, local ELO replay,
+    score combination, budget masking — fused into a single device
+    dispatch, with the budget selection folded into the replay kernel's
+    epilogue. The object API: it also returns the (Q, M) scores (with
+    infinite budgets, just the scores)."""
+    return _route(state, query_embs, budgets, costs, p_global=p_global,
+                  n_neighbors=n_neighbors, k=k, backend=backend,
+                  mode=mode, init_rating=init_rating)
 
 
 @partial(jax.jit,
          static_argnames=("mesh", "p_global", "n_neighbors", "k",
                           "backend", "mode", "init_rating"))
-def route_batch_choices_sharded(state: RouterState, query_embs, budgets,
-                                costs, *, mesh: Mesh,
-                                p_global: float = 0.5,
-                                n_neighbors: int = 20, k: float = 32.0,
-                                backend: str = "reference",
-                                mode: str = "combined",
-                                init_rating: float = elo.DEFAULT_RATING
-                                ) -> RouteChoices:
-    """route_batch_choices over a capacity-sharded RouterState
-    (DESIGN.md §12): one jitted dispatch whose retrieval chain runs
-    under shard_map over the DB axis — per-shard similarity + local
-    top-k, cross-shard candidate merge, replicated replay/selection
-    epilogue. Bit-identical choices/topk_idx to the single-device
-    oracle; `mesh` is static so each DB mesh compiles its own
-    executable (the dispatch cache keys on it)."""
-    if mode not in MODES:
-        raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
-    q = jnp.atleast_2d(jnp.asarray(query_embs, jnp.float32))
-    nq = q.shape[0]
-    m = state.n_models
-    n = min(n_neighbors, state.capacity)
-    costs = jnp.asarray(costs, jnp.float32)
-    budgets = jnp.broadcast_to(jnp.asarray(budgets, jnp.float32), (nq,))
-    if mode == "global":
-        # no retrieval: ratings/size replicate, so no shard_map either
-        scores = jnp.broadcast_to(state.global_ratings, (nq, m))
-        choices, _ = select_within_budget(scores, costs, budgets)
-        return RouteChoices(choices, jnp.full((nq, n), -1, jnp.int32))
-    if mode == "local":
-        init = jnp.full((m,), jnp.float32(init_rating))  # flat prior
-        p = 0.0   # 0*Global + 1*Local == Local, bit-exact for finite r
-    else:
-        init = state.global_ratings
-        p = p_global
-    axis = SHARD.DB_AXIS
+def route_batch_choices(state: RouterState, query_embs, budgets, costs, *,
+                        mesh: Optional[Mesh] = None,
+                        p_global: float = 0.5, n_neighbors: int = 20,
+                        k: float = 32.0, backend: str = "reference",
+                        mode: str = "combined",
+                        init_rating: float = elo.DEFAULT_RATING
+                        ) -> RouteChoices:
+    """The serving entry point: route_batch's dataflow, but the (Q, M)
+    score panel is never an output — only the fused-epilogue choices
+    and the retrieval trace leave the device. This is what the dispatch
+    cache (core/dispatch.py) pre-compiles per bucket.
 
-    def body(gr, init_b, emb, model_a, model_b, outcome, valid, size,
-             qq, bb, cc):
-        _, top_i, _, choices = KOPS.retrieve_replay_select_sharded(
-            qq, emb, model_a, model_b, outcome, valid, size, init_b, gr,
-            cc, bb, n=n, k=k, p=p, backend=backend, axis_name=axis)
-        return choices, top_i
-
-    shd = P(axis)
-    # check_vma=False: the merged epilogue output is replicated by
-    # construction (every shard reduces the same gathered pool), which
-    # shard_map's replication checker cannot prove through all_gather
-    fn = jax.shard_map(body, mesh=mesh,
-                       in_specs=(P(), P(), shd, shd, shd, shd, shd,
-                                 P(), P(), P(), P()),
-                       out_specs=(P(), P()), check_vma=False)
-    with jax.named_scope("eagle.retrieve_replay_select_sharded"):
-        choices, top_i = fn(state.global_ratings, init, state.emb,
-                            state.model_a, state.model_b, state.outcome,
-                            state.valid, state.size, q, budgets, costs)
-    return RouteChoices(choices, top_i)
+    With a DB `mesh` the state is capacity-sharded (DESIGN.md §12) and
+    the retrieval chain runs under shard_map over the DB axis; choices
+    and topk_idx are bit-identical to one device's. `mesh` is static,
+    so each DB mesh compiles its own executable (the dispatch cache
+    keys on it); the compiled module is `jit_route_batch_choices`
+    either way."""
+    res = _route(state, query_embs, budgets, costs, p_global=p_global,
+                 n_neighbors=n_neighbors, k=k, backend=backend, mode=mode,
+                 init_rating=init_rating, mesh=mesh)
+    return RouteChoices(res.choices, res.topk_idx)

@@ -6,21 +6,22 @@ backend:
   "pallas_interpret" the Pallas kernel body executed by the interpreter
                      (CPU-correctness validation of the TPU kernels)
   "pallas"           compiled Pallas (TPU target)
+
+The routing chain is ONE op, retrieve_replay_select: the same wrapper
+runs on one device and, given a mesh axis, as the per-shard body of a
+capacity-sharded DB (DESIGN.md §12).
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.elo_scan import elo_scan_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.retrieve_replay import (
-    retrieve_replay_pallas, retrieve_replay_select_pallas,
-    sharded_retrieve_replay_select_pallas)
+from repro.kernels.retrieve_replay import retrieve_replay_select_pallas
 from repro.kernels.similarity_topk import similarity_pallas
 
 
@@ -56,55 +57,32 @@ def elo_scan(ratings, a_idx, b_idx, outcome, valid, *, k: float = 32.0,
                      ratings, a_idx, b_idx, outcome, valid)
 
 
-@partial(jax.jit, static_argnames=("backend", "n", "k"))
-def retrieve_replay(q, emb, model_a, model_b, outcome, valid, size,
-                    init_ratings, *, n: int, k: float = 32.0,
-                    backend: str = "reference"):
-    """Fused routing retrieval: similarity panel + masked top-k + device
-    record gather + batched ELO replay, one dispatch, no host transfers.
-    Returns (local_ratings (Q,M), topk_idx (Q,n), topk_scores (Q,n))."""
-    return _dispatch(backend, partial(ref.retrieve_replay_ref, n=n, k=k),
-                     partial(retrieve_replay_pallas, n=n, k=k),
-                     q, emb, model_a, model_b, outcome, valid, size,
-                     init_ratings)
-
-
-@partial(jax.jit, static_argnames=("backend", "n", "k", "p"))
+@partial(jax.jit, static_argnames=("backend", "n", "k", "p", "axis_name"))
 def retrieve_replay_select(q, emb, model_a, model_b, outcome, valid, size,
                            init_ratings, global_ratings, costs, budgets, *,
                            n: int, k: float = 32.0, p: float = 0.5,
-                           backend: str = "reference"):
-    """retrieve_replay with the budget-selection epilogue fused in: the
-    replay stage also combines Score = p*Global + (1-p)*Local against
-    `global_ratings`, masks models costing over `budgets`, and emits the
-    per-query argmax (cheapest-model fallback) — the serving hot path
-    reads (Q,) choices with no second op over the (Q, M) scores.
+                           backend: str = "reference", axis_name=None):
+    """Fused routing retrieval: similarity panel + masked top-k + device
+    record gather + batched ELO replay, one dispatch, no host transfers,
+    with the budget-selection epilogue fused in: the replay stage also
+    combines Score = p*Global + (1-p)*Local against `global_ratings`,
+    masks models costing over `budgets`, and emits the per-query argmax
+    (cheapest-model fallback) — the serving hot path reads (Q,) choices
+    with no second op over the (Q, M) scores. A caller that wants only
+    Eagle-Local takes `local`: the epilogue does not touch it.
+
+    With `axis_name` this is the per-shard body of the capacity-sharded
+    chain (DESIGN.md §12), run under the caller's shard_map: the DB
+    panels arrive as this shard's contiguous row slice, and candidates
+    merge across `axis_name` inside.
+
     Returns (local (Q,M), topk_idx (Q,n), topk_scores (Q,n),
     choices (Q,) int32)."""
-    return _dispatch(backend,
-                     partial(ref.retrieve_replay_select_ref, n=n, k=k, p=p),
-                     partial(retrieve_replay_select_pallas, n=n, k=k, p=p),
-                     q, emb, model_a, model_b, outcome, valid, size,
-                     init_ratings, global_ratings, costs, budgets)
-
-
-def retrieve_replay_select_sharded(q, emb, model_a, model_b, outcome,
-                                   valid, size, init_ratings,
-                                   global_ratings, costs, budgets, *,
-                                   n: int, k: float = 32.0, p: float = 0.5,
-                                   backend: str = "reference",
-                                   axis_name: str = "db"):
-    """Capacity-sharded retrieve_replay_select: the per-shard body of
-    the DESIGN.md §12 routing chain. DB panels arrive as this shard's
-    contiguous row slice; candidates merge across `axis_name` inside.
-    Deliberately NOT jitted — it runs under shard_map inside the
-    caller's jit (core.state.route_batch_choices_sharded), where a
-    nested jit would only split the trace."""
     return _dispatch(
         backend,
-        partial(ref.sharded_retrieve_replay_select_ref, n=n, k=k, p=p,
+        partial(ref.retrieve_replay_select_ref, n=n, k=k, p=p,
                 axis_name=axis_name),
-        partial(sharded_retrieve_replay_select_pallas, n=n, k=k, p=p,
+        partial(retrieve_replay_select_pallas, n=n, k=k, p=p,
                 axis_name=axis_name),
         q, emb, model_a, model_b, outcome, valid, size, init_ratings,
         global_ratings, costs, budgets)

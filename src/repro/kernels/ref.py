@@ -1,11 +1,10 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import similarity_topk as ST
 from repro.kernels.similarity_topk import panel_topk
 
 
@@ -98,55 +97,76 @@ def budget_select_ref(scores, costs, budgets):
 
 def retrieve_replay_pipeline(similarity_fn, replay_fn, q, emb, model_a,
                              model_b, outcome, valid, size, init_ratings,
-                             *, n):
+                             *, n, axis_name=None):
     """The fused retrieval chain — similarity panel -> live-row masked
     top-k (panel_topk) -> farthest-first record gather -> replay from
     the broadcast prior — with the stage implementations injected, so
     the reference and Pallas backends share ONE copy of the glue and
-    cannot drift.
+    cannot drift. `replay_fn(init, a, b, s, v)` returns (local,
+    choices). Returns (local (Q,M), topk_idx (Q,n), topk_scores (Q,n),
+    choices (Q,)).
 
-    replay_fn may return either `local` or a `(local, *extras)` tuple
-    (the fused budget-selection epilogue returns `(local, choices)`);
-    extras are appended to the pipeline's return tuple.
+    With `axis_name` the chain is the per-shard body of a shard_map
+    over a capacity-sharded DB (DESIGN.md §12): the panels arrive as
+    this shard's CONTIGUOUS row range, the queries and the prior
+    replicated. Each shard keeps its top min(n, C_local) rows, live by
+    their global index (row + this shard's offset) — no shard holds
+    more of the global top-n — gathers their records, and
+    shard_merge_topk all-gathers the candidates, records carried by
+    position, and reduces them to the global top n. Bit-identical to
+    the chain over the full panels: slicing the similarity matmul on
+    the row dim leaves each score column's D-accumulation untouched,
+    and the merge's (shard, local rank) pool order reproduces one
+    device's top_k tie-breaking under the contiguous partition.
 
     Each stage runs under a named scope (`eagle.similarity`,
-    `eagle.topk`, `eagle.gather`, `eagle.replay`), so its device ops
-    carry a stable name in a profile whatever their shapes."""
+    `eagle.topk`, `eagle.gather`, `eagle.merge` on a mesh,
+    `eagle.replay`), so its device ops carry a stable name in a profile
+    whatever their shapes."""
+    nq = q.shape[0]
     with jax.named_scope("eagle.similarity"):
         scores = similarity_fn(q, emb)
+    offset = 0 if axis_name is None else \
+        jax.lax.axis_index(axis_name) * emb.shape[0]
     with jax.named_scope("eagle.topk"):
-        top_s, top_i = panel_topk(scores, n, size)
-    with jax.named_scope("eagle.gather"):
-        hit = jnp.isfinite(top_s)
-        a, b, s, v = gather_records(model_a, model_b, outcome, valid,
-                                    top_i, hit)
+        top_s, top_i = panel_topk(scores, min(n, emb.shape[0]), size,
+                                  offset)
+    if axis_name is None:
+        with jax.named_scope("eagle.gather"):
+            records = gather_records(model_a, model_b, outcome, valid,
+                                     top_i, jnp.isfinite(top_s))
+    else:
+        with jax.named_scope("eagle.gather"):
+            cand = tuple(jnp.take(x, top_i, axis=0)
+                         for x in (model_a, model_b, outcome, valid))
+        with jax.named_scope("eagle.merge"):
+            # looked up at trace time: a fault planted in the module
+            # reaches the traced chain
+            top_s, top_i, (ca, cb, cs, cv) = ST.shard_merge_topk(
+                top_s, top_i + offset, cand, n, axis_name)
+            # farthest-first flatten of the MERGED candidates —
+            # gather_records' replay order, minus the row gather
+            hit = jnp.flip(jnp.isfinite(top_s), axis=1)[..., None]
+            records = (jnp.flip(ca, axis=1).reshape(nq, -1),
+                       jnp.flip(cb, axis=1).reshape(nq, -1),
+                       jnp.flip(cs, axis=1).reshape(nq, -1),
+                       (jnp.flip(cv, axis=1) & hit).reshape(nq, -1))
     with jax.named_scope("eagle.replay"):
-        init = jnp.broadcast_to(init_ratings,
-                                (q.shape[0], init_ratings.shape[-1]))
-        out = replay_fn(init, a, b, s, v)
-    local, extras = (out[0], tuple(out[1:])) if isinstance(out, tuple) \
-        else (out, ())
-    return (local, top_i, top_s) + extras
-
-
-def retrieve_replay_ref(q, emb, model_a, model_b, outcome, valid, size,
-                        init_ratings, *, n, k=32.0):
-    """Fused routing retrieval oracle: similarity panel -> masked top-k ->
-    device gather -> batched ELO replay. Returns (local (Q,M), topk_idx,
-    topk_scores)."""
-    return retrieve_replay_pipeline(
-        similarity_ref, partial(elo_replay_ref, k=k), q, emb, model_a,
-        model_b, outcome, valid, size, init_ratings, n=n)
+        init = jnp.broadcast_to(init_ratings, (nq, init_ratings.shape[-1]))
+        local, choices = replay_fn(init, *records)
+    return local, top_i, top_s, choices
 
 
 def retrieve_replay_select_ref(q, emb, model_a, model_b, outcome, valid,
                                size, init_ratings, global_ratings, costs,
-                               budgets, *, n, k=32.0, p=0.5):
-    """retrieve_replay with the budget-selection epilogue fused in: the
-    replay stage also combines Score = p*Global + (1-p)*Local and picks
-    the best affordable model, so the caller reads (Q,) choices without
-    a second op over the (Q, M) scores. Returns (local (Q,M), topk_idx,
-    topk_scores, choices (Q,))."""
+                               budgets, *, n, k=32.0, p=0.5, axis_name=None):
+    """Fused routing oracle: similarity panel -> masked top-k -> device
+    gather -> batched ELO replay, with the budget-selection epilogue
+    fused into the replay stage: it combines Score = p*Global +
+    (1-p)*Local and picks the best affordable model, so the caller
+    reads (Q,) choices without a second op over the (Q, M) scores.
+    `axis_name` as retrieve_replay_pipeline. Returns (local (Q,M),
+    topk_idx (Q,n), topk_scores, choices (Q,))."""
 
     def replay_select(init, a, b, s, v):
         local = elo_replay_ref(init, a, b, s, v, k=k)
@@ -154,81 +174,6 @@ def retrieve_replay_select_ref(q, emb, model_a, model_b, outcome, valid,
         return local, budget_select_ref(combined, costs, budgets)
 
     return retrieve_replay_pipeline(
-        similarity_ref, replay_select, q, emb, model_a, model_b, outcome,
-        valid, size, init_ratings, n=n)
-
-
-def sharded_retrieve_replay_pipeline(similarity_fn, replay_fn, q, emb,
-                                     model_a, model_b, outcome, valid,
-                                     size, init_ratings, *, n,
-                                     axis_name):
-    """Per-shard body of the capacity-sharded retrieval chain, run
-    under shard_map over `axis_name` (DESIGN.md §12): the DB panels
-    arrive as this shard's CONTIGUOUS row range, the queries and the
-    replay prior arrive replicated. Stages:
-
-      local similarity panel -> local top min(n, C_local) of the
-      rows live by their global index (shard_local_topk) -> local
-      candidate-record gather ->
-      cross-shard merge (all-gather + final top-n reduce, candidates'
-      records carried by position) -> farthest-first flatten ->
-      replicated replay + epilogue.
-
-    Bit-identical to retrieve_replay_pipeline over the full panels:
-    slicing the similarity matmul on the row dim leaves each score
-    column's D-accumulation untouched, and the merge's (shard, local
-    rank) pool order reproduces single-device top_k tie-breaking under
-    the contiguous partition (see shard_merge_topk). Like the
-    unsharded glue, both backends share this ONE copy, under the same
-    stage scopes plus `eagle.merge` for the all-gather and merge."""
-    from repro.kernels.similarity_topk import (shard_local_topk,
-                                               shard_merge_topk)
-    with jax.named_scope("eagle.similarity"):
-        scores = similarity_fn(q, emb)
-    c_local = emb.shape[0]
-    offset = jax.lax.axis_index(axis_name) * c_local
-    with jax.named_scope("eagle.topk"):
-        loc_s, loc_i = shard_local_topk(scores, n, size, offset)
-    with jax.named_scope("eagle.gather"):
-        records = tuple(jnp.take(x, loc_i, axis=0)
-                        for x in (model_a, model_b, outcome, valid))
-    with jax.named_scope("eagle.merge"):
-        top_s, top_i, (ca, cb, cs, cv) = shard_merge_topk(
-            loc_s, loc_i + offset, records, n, axis_name)
-    nq = q.shape[0]
-    with jax.named_scope("eagle.replay"):
-        hit = jnp.isfinite(top_s)
-        # farthest-first flatten of the MERGED candidates —
-        # gather_records' replay-order contract, minus the row gather
-        # it already did
-        a = jnp.flip(ca, axis=1).reshape(nq, -1)
-        b = jnp.flip(cb, axis=1).reshape(nq, -1)
-        s = jnp.flip(cs, axis=1).reshape(nq, -1)
-        v = (jnp.flip(cv, axis=1)
-             & jnp.flip(hit, axis=1)[..., None]).reshape(nq, -1)
-        init = jnp.broadcast_to(init_ratings, (nq, init_ratings.shape[-1]))
-        out = replay_fn(init, a, b, s, v)
-    local, extras = (out[0], tuple(out[1:])) if isinstance(out, tuple) \
-        else (out, ())
-    return (local, top_i, top_s) + extras
-
-
-def sharded_retrieve_replay_select_ref(q, emb, model_a, model_b, outcome,
-                                       valid, size, init_ratings,
-                                       global_ratings, costs, budgets, *,
-                                       n, k=32.0, p=0.5,
-                                       axis_name="db"):
-    """Capacity-sharded retrieve_replay_select_ref: same fused replay +
-    budget-selection epilogue, run on the merged cross-shard
-    candidates. Returns (local (Q,M), topk_idx (Q,n) GLOBAL rows,
-    topk_scores, choices (Q,))."""
-
-    def replay_select(init, a, b, s, v):
-        local = elo_replay_ref(init, a, b, s, v, k=k)
-        combined = p * global_ratings[None, :] + (1.0 - p) * local
-        return local, budget_select_ref(combined, costs, budgets)
-
-    return sharded_retrieve_replay_pipeline(
         similarity_ref, replay_select, q, emb, model_a, model_b, outcome,
         valid, size, init_ratings, n=n, axis_name=axis_name)
 

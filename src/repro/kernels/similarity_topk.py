@@ -145,27 +145,18 @@ def panel_topk(scores, n: int, size, offset=0):
 
 
 # ---------------------------------------------------------------------------
-# capacity-sharded retrieval: local top-k + cross-shard merge (DESIGN.md §12)
+# capacity-sharded retrieval: the cross-shard merge (DESIGN.md §12)
 # ---------------------------------------------------------------------------
 
-def shard_local_topk(scores, n: int, size, offset):
-    """Per-shard candidate reduce over a LOCAL score panel (Q, C_l)
-    whose first row is global row `offset`: keep min(n, C_l) candidates,
-    rows at or past the global live `size` scoring -inf. That per-shard
-    k is exact — any single shard can contribute at most min(n, C_l)
-    rows of the global top-n, so the merged pool provably contains the
-    true global top-n. Returns (top_scores (Q, kl), top_local_idx
-    (Q, kl))."""
-    return panel_topk(scores, min(n, scores.shape[-1]), size, offset)
-
-
 def shard_merge_topk(top_s, top_i, payloads, n: int, axis_name: str):
-    """Cross-shard top-k merge: all-gather every shard's kl candidates
-    (XLA lowers the gather as a ring/tree exchange), pool them per
-    query, and take the final top-n reduce. `payloads` are per-shard
-    candidate tensors (Q, kl, ...) carried through the merge by
-    position, so the winners' records arrive with them and no second
-    cross-shard gather of arbitrary rows is needed.
+    """Cross-shard top-k merge: all-gather every shard's kl =
+    min(n, C_local) candidates (XLA lowers the gather as a ring/tree
+    exchange), pool them per query, and take the final top-n reduce.
+    No shard holds more than kl of the global top-n, so the pool holds
+    all of it. `payloads` are per-shard candidate tensors (Q, kl, ...)
+    carried through the merge by position, so the winners' records
+    arrive with them and no second cross-shard gather of arbitrary rows
+    is needed.
 
     Tie-breaking contract: the pool is ordered (shard asc, local rank
     asc). jax.lax.top_k breaks ties toward the lowest index, and local

@@ -6,7 +6,7 @@ parent test spawns THIS script once with
 `XLA_FLAGS=--xla_force_host_platform_device_count=4`, and it prints a
 single JSON report line covering the whole matrix —
 
-  * route_batch_choices_sharded vs the single-device oracle, bitwise,
+  * route_batch_choices on a DB mesh vs the single-device oracle, bitwise,
     on {1,2,4}-shard meshes x all routing modes x both exercisable
     backends (reference, pallas_interpret);
   * tie-breaking stress: duplicate embeddings straddling every shard
@@ -28,11 +28,11 @@ single JSON report line covering the whole matrix —
   * the named device scopes of the 4-shard route (both backends) and
     of the owner-scatter, and the owner-scatter's module name, in their
     lowered HLO;
+  * the compiled module names of the route with and without a mesh and
+    of the one-device scatter;
   * a DB wide enough that every shard's top-k takes two stages
     (kernels/similarity_topk.py panel_topk), through the dispatcher on
-    each mesh and both backends: bitwise equal to one device, with
-    dispatch_two_stage_topk_total counting every dispatch there and
-    none at the small capacity above;
+    each mesh and both backends: bitwise equal to one device;
   * a seeded property-style table the parent replays through the
     hypothesis shim.
 """
@@ -94,9 +94,13 @@ def main():
 
     def sharded_route(mesh, state, q, budgets, **kw):
         sstate = STATE.shard_state(state, mesh)
-        return STATE.route_batch_choices_sharded(
+        return STATE.route_batch_choices(
             sstate, rep(mesh, q), rep(mesh, budgets), rep(mesh, costs),
             mesh=mesh, **kw)
+
+    def module(compiled):
+        """The compiled program's module name, as a trace names it."""
+        return compiled.as_text().split()[1].rstrip(",")
 
     def equal(a, b):
         return bool(np.array_equal(np.asarray(jax.device_get(a)),
@@ -160,7 +164,7 @@ def main():
                   for f in ("global_ratings", "emb", "model_a",
                             "model_b", "outcome", "valid", "size")}
         want = STATE.route_batch_choices(st_o, q, budgets, costs)
-        got = STATE.route_batch_choices_sharded(
+        got = STATE.route_batch_choices(
             st_s, rep(mesh, q), rep(mesh, budgets), rep(mesh, costs),
             mesh=mesh)
         fields["route"] = equal(want.choices, got.choices) and \
@@ -245,13 +249,13 @@ def main():
         st = STATE.commit(db3, ratings, None, mesh=mesh)
         qd, bd, cd = rep(mesh, q), rep(mesh, budgets), rep(mesh, costs)
         for _ in range(2):   # warmup MUST include real feedback+commit
-            STATE.route_batch_choices_sharded(
+            STATE.route_batch_choices(
                 st, qd, bd, cd, mesh=mesh).choices.block_until_ready()
             feedback()
             st = STATE.commit(db3, ratings, st, mesh=mesh)
         with CompileCounter() as cc:
             for _ in range(6):
-                STATE.route_batch_choices_sharded(
+                STATE.route_batch_choices(
                     st, qd, bd, cd, mesh=mesh).choices.block_until_ready()
                 feedback()
                 st = STATE.commit(db3, ratings, st, mesh=mesh)
@@ -263,7 +267,7 @@ def main():
     sstate = STATE.shard_state(state, mesh)
     report["scopes"] = {}
     for bk in BACKENDS:
-        txt = STATE.route_batch_choices_sharded.lower(
+        txt = STATE.route_batch_choices.lower(
             sstate, rep(mesh, q), rep(mesh, budgets), rep(mesh, costs),
             mesh=mesh, backend=bk).as_text(debug_info=True)
         report["scopes"][bk] = sorted(
@@ -277,21 +281,32 @@ def main():
         sstate.valid, packed)
     report["scopes"]["commit"] = \
         "eagle.commit_scatter" in lowered.as_text(debug_info=True)
-    report["scatter_module"] = \
-        lowered.compile().as_text().split()[1].rstrip(",")
+    report["scatter_module"] = module(lowered.compile())
+
+    # -- the compiled modules bench/lib/readers.py matches ---------------
+    report["modules"] = {"route": {
+        str(s): module(STATE.route_batch_choices.lower(
+            STATE.shard_state(state, meshes[s]), rep(meshes[s], q),
+            rep(meshes[s], budgets), rep(meshes[s], costs),
+            mesh=meshes[s]).compile())
+        for s in MESHES}}
+    report["modules"]["route"]["none"] = module(
+        STATE.route_batch_choices.lower(state, q, budgets, costs).compile())
+    report["modules"]["scatter"] = module(STATE._scatter_rows.lower(
+        state.emb, state.model_a, state.model_b, state.outcome,
+        state.valid,
+        np.zeros((2, STATE.packed_width(D, RCAP)), np.int32)).compile())
 
     # -- wide DB: the two-stage top-k on every shard ----------------------
     from repro.kernels.similarity_topk import two_stage_topk
 
     def counted(mesh, st, backend, queries, rounds=3):
         """The last of `rounds` dispatches, and the dispatcher's
-        (dispatch count, two-stage count)."""
+        dispatch count."""
         d = RouteDispatcher(costs, backend=backend, mesh=mesh,
                             obs=OBS.Observability())
         out = [d.route_result(st, queries, budgets) for _ in range(rounds)]
-        reg = d.obs.registry
-        return out[-1], (reg.counter("dispatch_calls_total").value,
-                         reg.counter("dispatch_two_stage_topk_total").value)
+        return out[-1], d.obs.registry.counter("dispatch_calls_total").value
 
     rw = np.random.default_rng(300)
     db_w = VectorDB(D, capacity=WIDE_CAP, records_per_query=RCAP)
@@ -318,10 +333,10 @@ def main():
                 "equal": bool(np.array_equal(ch, np.asarray(want.choices))
                               and np.array_equal(
                                   topk, np.asarray(want.topk_idx))),
-                "calls": calls[0], "two_stage": calls[1]}
+                "calls": calls}
     _, calls = counted(meshes[4], STATE.shard_state(state, meshes[4]),
                        "reference", q)
-    report["wide"]["narrow"] = {"calls": calls[0], "two_stage": calls[1]}
+    report["wide"]["narrow"] = {"calls": calls}
 
     # -- seeded property-style table (replayed via the shim) -------------
     report["seeded"] = {}

@@ -217,16 +217,18 @@ def _traced_with_one_stage_topk():
         jax.clear_caches()
 
 
-def _wide_router(backend):
-    """12,001 live rows of 2^14 (the live edge inside a chunk); every
-    tenth row repeats an earlier row's embedding, so scores tie, and
-    five of the 13 queries are such rows."""
+def _wide_router(backend, capacity=WIDE_CAPACITY, mode="combined"):
+    """12,001 live rows of 2^14 (the live edge inside a chunk), or 40 of
+    a smaller capacity; every tenth row repeats an earlier row's
+    embedding, so scores tie, and five of the 13 queries are such
+    rows."""
     rng = np.random.default_rng(11)
-    n_models, n = 5, 12_001
-    r = EagleRouter([f"m{i}" for i in range(n_models)],
-                    np.arange(1, n_models + 1.0),
-                    EagleConfig(embed_dim=8, backend=backend),
-                    db_capacity=WIDE_CAPACITY)
+    n_models = 5
+    n = 12_001 if capacity == WIDE_CAPACITY else 40
+    r = ROUTERS[mode]([f"m{i}" for i in range(n_models)],
+                      np.arange(1, n_models + 1.0),
+                      EagleConfig(embed_dim=8, backend=backend),
+                      db_capacity=capacity)
     emb = rng.normal(size=(n, 8)).astype(np.float32)
     emb[10::10] = emb[:len(emb[10::10])]
     a = rng.integers(0, n_models, n)
@@ -234,20 +236,29 @@ def _wide_router(backend):
     r.fit(emb, a, b, rng.choice([0.0, 0.5, 1.0], n),
           query_id=np.arange(n))
     q = rng.normal(size=(13, 8)).astype(np.float32)
-    q[:5] = emb[[0, 3, 7, 500, 1100]]
+    q[:5] = emb[np.array([0, 3, 7, 500, 1100]) % n]
     return r, q, rng.uniform(0.5, 6.0, 13).astype(np.float32)
 
 
 @pytest.mark.parametrize("backend", ["reference", "pallas_interpret"])
-def test_two_stage_route_bit_identical_to_one_stage(backend):
-    """At a capacity that takes the two-stage top-k, route_batch_choices
-    and the dispatcher return the rows and choices of the same route
-    traced with lax.top_k, bit for bit, ties included."""
-    r, q, budgets = _wide_router(backend)
-    assert two_stage_topk(r.state.capacity, r.cfg.n_neighbors)
+@pytest.mark.parametrize("capacity,mode", [
+    (64, "combined"), (WIDE_CAPACITY, "combined"),
+    (WIDE_CAPACITY, "global")])
+def test_two_stage_route_bit_identical_to_one_stage(capacity, mode,
+                                                    backend):
+    """At a capacity that takes the two-stage top-k, and at one that
+    does not (or with no retrieval at all, in global mode),
+    route_batch_choices and the dispatcher's route and route_result
+    return the rows and choices of the same route traced with
+    lax.top_k, bit for bit, ties included; each dispatch counts once."""
+    r, q, budgets = _wide_router(backend, capacity, mode)
+    assert r.state.capacity == capacity
+    assert two_stage_topk(capacity, r.cfg.n_neighbors) == \
+        (capacity == WIDE_CAPACITY)
     got = route_batch_choices(r.state, q, budgets, r.costs, **r._kw())
-    got_c, got_i = RouteDispatcher.for_router(r).route_result(
-        r.state, q, budgets)
+    d = RouteDispatcher.for_router(r)
+    got_c, got_i = d.route_result(r.state, q, budgets)
+    routed = d.route(r.state, q, budgets)
     with _traced_with_one_stage_topk():
         want = route_batch_choices(r.state, q, budgets, r.costs, **r._kw())
     want_i, want_c = np.asarray(want.topk_idx), np.asarray(want.choices)
@@ -255,24 +266,8 @@ def test_two_stage_route_bit_identical_to_one_stage(backend):
     np.testing.assert_array_equal(np.asarray(got.choices), want_c)
     np.testing.assert_array_equal(got_i, want_i)
     np.testing.assert_array_equal(got_c, want_c)
-
-
-@pytest.mark.parametrize("capacity,mode,two_stage", [
-    (64, "combined", False), (WIDE_CAPACITY, "combined", True),
-    (WIDE_CAPACITY, "global", False)])
-def test_two_stage_counter_counts_its_dispatches(capacity, mode, two_stage):
-    """dispatch_two_stage_topk_total counts every dispatch whose
-    executable takes the two-stage top-k, and none other."""
-    r, rng = _router(seed=12, capacity=capacity, mode=mode)
-    d = RouteDispatcher.for_router(r, max_bucket=16)
-    for nq in (3, 9, 30):                  # 30 rows: two dispatches
-        d.route(r.state, rng.normal(size=(nq, 8)).astype(np.float32), 3.0)
-    d.route_result(r.state, rng.normal(size=(5, 8)).astype(np.float32), 3.0)
-    reg = d.obs.registry
-    calls = reg.counter("dispatch_calls_total").value
-    assert calls == 5
-    assert reg.counter("dispatch_two_stage_topk_total").value == \
-        (calls if two_stage else 0)
+    np.testing.assert_array_equal(routed, want_c)
+    assert d.obs.registry.counter("dispatch_calls_total").value == 2
 
 
 # ---------------------------------------------------------------------------

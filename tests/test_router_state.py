@@ -16,9 +16,8 @@ from repro.core import elo
 from repro.core.router import (EagleConfig, EagleRouter, GlobalOnlyRouter,
                                LocalOnlyRouter, combine_scores,
                                select_within_budget)
-from repro.core.state import (DoubleBuffer, RouterState, batch_scores,
-                              commit, init_state, route_batch,
-                              state_from_buffer)
+from repro.core.state import (DoubleBuffer, RouterState, commit,
+                              init_state, route_batch, state_from_buffer)
 from repro.core.vectordb import VectorDB
 from repro.kernels import ops
 
@@ -156,7 +155,7 @@ def test_commit_guards_stale_dirty_rows_after_clear():
     # empty DB: retrieval is fully masked, scores fall back to the prior
     q = rng.normal(size=(2, 8)).astype(np.float32)
     np.testing.assert_allclose(
-        np.asarray(batch_scores(s2, q)),
+        np.asarray(route_batch(s2, q, jnp.inf, router.costs).scores),
         np.tile(np.asarray(s2.global_ratings), (2, 1)), rtol=1e-6)
 
 
@@ -281,15 +280,20 @@ def test_retrieve_replay_backend_parity(nq, cap, rcap, d, m, n):
     o = jnp.asarray(rng.choice([0.0, 0.5, 1.0], (cap, rcap)), jnp.float32)
     v = jnp.asarray(rng.random((cap, rcap)) > 0.3)
     init = jnp.asarray(1000 + 40 * rng.normal(size=(m,)), jnp.float32)
+    costs = jnp.asarray(rng.uniform(1.0, 4.0, m), jnp.float32)
+    budgets = jnp.asarray(rng.uniform(0.5, 4.5, nq), jnp.float32)
     n_eff = min(n, cap)
-    ref_out = ops.retrieve_replay(q, emb, a, b, o, v, size, init, n=n_eff,
-                                  k=32.0, backend="reference")
-    pal_out = ops.retrieve_replay(q, emb, a, b, o, v, size, init, n=n_eff,
-                                  k=32.0, backend="pallas_interpret")
+    ref_out, pal_out = (
+        ops.retrieve_replay_select(q, emb, a, b, o, v, size, init, init,
+                                   costs, budgets, n=n_eff, k=32.0,
+                                   backend=bk)
+        for bk in ("reference", "pallas_interpret"))
     np.testing.assert_array_equal(np.asarray(ref_out[1]),
                                   np.asarray(pal_out[1]))
     np.testing.assert_allclose(np.asarray(ref_out[0]),
                                np.asarray(pal_out[0]), rtol=1e-5, atol=1e-3)
+    np.testing.assert_array_equal(np.asarray(ref_out[3]),
+                                  np.asarray(pal_out[3]))
 
 
 # ---------------------------------------------------------------------------

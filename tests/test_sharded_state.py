@@ -22,7 +22,8 @@ from _hypothesis_compat import given, settings, st
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-from bench.lib.readers import SHARD_SCATTER_MODULE  # noqa: E402
+from bench.lib.readers import (ROUTE_MODULE, SCATTER_MODULE,  # noqa: E402
+                               SHARD_SCATTER_MODULE)
 
 _REPORT = {}
 
@@ -51,7 +52,7 @@ def test_worker_saw_forced_devices():
 
 
 def test_sharded_routing_bit_identical_all_meshes_modes_backends():
-    """route_batch_choices_sharded == route_batch_choices, bitwise
+    """route_batch_choices on a DB mesh == on one device, bitwise
     (choices AND topk_idx), on 1/2/4-shard meshes for every routing
     mode x both exercisable backends."""
     equiv = report()["equiv"]
@@ -136,13 +137,23 @@ def test_sharded_programs_carry_the_stage_scopes():
 def test_two_stage_topk_shards_bit_identical_and_counted():
     """A DB wide enough that every shard's top-k takes two stages, with
     ties straddling the shard boundaries: the dispatcher's route equals
-    one device's bit for bit on every mesh and backend, and counts each
-    of its dispatches as two-stage; at the small capacity it counts
-    none."""
+    one device's bit for bit on every mesh and backend, on each of its
+    dispatches; at the small capacity it dispatches the same."""
     wide = report()["wide"]
     assert wide["two_stage_per_shard"] == {m: True for m in MESHES}
     for mesh in MESHES:
         for bk in ("reference", "pallas_interpret"):
             assert wide[f"{mesh}/{bk}"] == {
-                "equal": True, "calls": 3, "two_stage": 3}, (mesh, bk)
-    assert wide["narrow"] == {"calls": 3, "two_stage": 0}
+                "equal": True, "calls": 3}, (mesh, bk)
+    assert wide["narrow"] == {"calls": 3}
+
+
+def test_route_and_scatter_compile_to_the_modules_the_readers_match():
+    """The per-layer readers (bench/lib/readers.py) find the route by
+    the prefix of its module, with and without a DB mesh, and the
+    one-device commit scatter by its module's name."""
+    modules = report()["modules"]
+    for mesh, name in modules["route"].items():
+        assert name.startswith(ROUTE_MODULE), (mesh, name)
+    assert set(modules["route"]) == set(MESHES) | {"none"}
+    assert modules["scatter"] == SCATTER_MODULE

@@ -24,7 +24,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 from repro import sharding as SHARD
 from repro.core import state as STATE
 from repro.core.state import (RouterState, route_batch_choices,
-                              route_batch_choices_sharded, state_shardings)
+                              state_shardings)
 from repro.kernels.elo_scan import elo_scan_pallas, elo_scan_select_pallas
 from repro.kernels.similarity_topk import similarity_pallas, two_stage_topk
 
@@ -164,7 +164,7 @@ def test_sharded_route_and_commit_compile_on_four_described_chips(topo):
     mesh = Mesh(np.asarray(topo.devices[:4]), (SHARD.DB_AXIS,),
                 axis_types=(AxisType.Auto,))
     rep = NamedSharding(mesh, P())
-    c = route_batch_choices_sharded.lower(
+    c = route_batch_choices.lower(
         _state(1 << 20, state_shardings(mesh)),
         _sds((Q, D), jnp.float32, rep), _sds((Q,), jnp.float32, rep),
         _sds((M,), jnp.float32, rep), mesh=mesh, **KW).compile()
